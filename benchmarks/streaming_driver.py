@@ -10,6 +10,8 @@ plus the peak resident window (must stay under the configured bound).
 ``scale_up()`` AND one ``drain()``, then a subprocess SIGKILL mid-job
 followed by a resume whose merged output must be byte-identical to the
 uninterrupted run's (only the ledger's tail segment may be replayed).
+The children run SimEngine replicas and touch no device: keep it so, since
+a chip belongs to one process at a time.
 """
 from __future__ import annotations
 

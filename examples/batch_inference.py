@@ -22,11 +22,13 @@ import argparse
 import os
 import time
 
+import jax
 import numpy as np
 
 from repro.configs import default_sampling, reduced_config
 from repro.core.events import SeqFinishedEvent, TokenBlockEvent
 from repro.core.scheduler import CoroutineScheduler, SchedulerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.engine import NodeEngine
 from repro.sampling import SamplingParams
 
@@ -39,8 +41,10 @@ def longtail_lengths(rng, n, mean=12, sigma=1.0, cap=80):
 def run(enable_coroutines: bool):
     cfg = reduced_config("phi3_5_moe")
     rng = np.random.default_rng(1)
+    devs = jax.devices()        # one node per chip where there are several
     engines = [NodeEngine(cfg, node_id=i, max_active=4, max_len=128,
-                          page_size=16, seed=0) for i in range(2)]
+                          page_size=16, seed=0, device=devs[i % len(devs)])
+               for i in range(2)]
     # OFF baseline: threshold -> 0+ means refill only when the node is
     # completely drained (static batch-at-a-time), no mid-flight COMBINE
     sc = SchedulerConfig(page_size=16,
@@ -142,9 +146,12 @@ def run_jsonl(inp: str, out: str, *, gen: int = 0, replicas: int = 1,
                                   vocab=cfg.vocab_size).write_jsonl(inp)
         print(f"[jsonl] generated {n} long-tail requests -> {inp}")
 
+    devs = jax.devices()
+
     def factory(rid):       # one replica = two NodeEngine nodes
         return [NodeEngine(cfg, node_id=rid * 100 + i, max_active=4,
-                           max_len=128, page_size=16, seed=0)
+                           max_len=128, page_size=16, seed=0,
+                           device=devs[(2 * rid + i) % len(devs)])
                 for i in range(2)]
 
     drv = StreamingJobDriver(
@@ -192,6 +199,7 @@ if __name__ == "__main__":
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--window", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.jsonl:
         run_jsonl(args.jsonl[0], args.jsonl[1], gen=args.gen,
                   replicas=args.replicas, window=args.window)

@@ -15,7 +15,9 @@ uninterrupted run byte for byte.
 an uninterrupted reference run, a run SIGKILLed after K finishes (real
 signal 9 — no atexit, no flush), and a resumed run; it asserts the
 resumed output file equals the reference byte for byte and that the
-resume skipped every journaled request.
+resume skipped every journaled request.  A chip belongs to one process
+at a time: the parent only launches and touches no device array, so each
+child in turn can hold the chip.
 """
 import argparse
 import json

@@ -1,5 +1,5 @@
-"""Train a ~100M-class model (SmolLM-360M family, width-reduced to fit this
-CPU container) for a few hundred steps with the production train_step:
+"""Train a ~100M-class model (SmolLM-360M family, width-reduced to train on a
+CPU) for a few hundred steps with the production train_step:
 microbatched grad accumulation + ZeRO-1 AdamW + remat + flash attention.
 
     PYTHONPATH=src python examples/train_smollm.py [--steps 200]

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.api import MeshAxes, ModelConfig
 from repro.models import transformer as T
 
@@ -249,7 +248,7 @@ def make_hint(cfg: ModelConfig, axes: MeshAxes, tp: int):
     M = axes.model
 
     def hint(q, k, v):
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh.empty or M not in mesh.axis_names:
             return q, k, v
         wsc = jax.lax.with_sharding_constraint

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG = -1e30
 
@@ -114,7 +113,7 @@ def flash_attention_tpu(q, k, v, *, causal=True, window=0, block_q=128,
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
             pltpu.VMEM((block_q, dv), jnp.float32),  # acc
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

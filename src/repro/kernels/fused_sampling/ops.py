@@ -4,23 +4,23 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fused_sampling.fused_sampling import (TILE,
+from repro.kernels.fused_sampling.fused_sampling import (LANES, OUT_COLS,
+                                                         ROWS, TILE,
                                                          fused_sampling_tpu)
 from repro.kernels.fused_sampling.ref import NEG
 
+# VMEM budget for the parked (ROWS, V) logits block: park whenever it
+# fits.  v5e's compiler accepts a 12 MiB park and refuses 14 MiB (16 MiB
+# scoped VMEM, the rest holds the double-buffered input tiles, the
+# (NB, 128) histograms and the binning temporaries); the cap keeps 4 MiB
+# of margin.  At qwen2's vocabulary (V 151936 -> 152064 padded) the
+# parked block is 4.6 MiB.
+PARK_VMEM_LIMIT = 8 << 20
 
-def _pad_rows(x, vp, fill):
+
+def _pad(x, rows, cols, fill):
     B, V = x.shape
-    if V == vp:
-        return x
-    return jnp.concatenate(
-        [x, jnp.full((B, vp - V), fill, x.dtype)], axis=1)
-
-
-# VMEM budget for the parked logits row: park whenever the padded row
-# fits (128k f32 vocab = 512 KiB; the cap leaves headroom for the
-# histogram/lane scratch and double-buffered input tiles)
-PARK_VMEM_LIMIT = 1 << 20
+    return jnp.pad(x, ((0, rows - B), (0, cols - V)), constant_values=fill)
 
 
 @partial(jax.jit,
@@ -31,30 +31,40 @@ def fused_sample(logits, gumbel, k, p, min_p, raw=None, *, lp_k: int = 0,
     """Single-pass sample for a (B, V) batch of processed logits.
 
     Pads V up to a TILE multiple with the NEG sentinel (padded tokens
-    carry zero probability mass and can never win either argmax).
-    ``park_vmem`` (default: auto — on whenever the padded row fits
-    ``PARK_VMEM_LIMIT``) parks the logits row in VMEM across the kernel's
-    phases so HBM reads it once instead of once per phase.
+    carry zero probability mass and can never win either argmax) and B
+    up to a ROWS multiple with NEG rows (dropped from the outputs).
+    ``park_vmem`` (default: auto — on whenever a row group fits
+    ``PARK_VMEM_LIMIT``) parks the logits rows in VMEM across the
+    kernel's phases so HBM reads them once instead of once per phase.
     Returns a dict with ``sampled``/``greedy`` (B,) i32, ``tau``/``m``/
     ``l`` (B,) f32, plus — when ``with_lanes`` — the raw-logit softmax
     stats ``m_raw``/``l_raw`` and, for ``lp_k > 0``, the ``top_vals``/
     ``top_idx`` lanes ((B, lp_k), raw-logit values with lax.top_k
     tie-breaking; log-softmax = top_vals - m_raw - log(l_raw)).
     """
-    vp = -(-logits.shape[1] // TILE) * TILE
+    B, V = logits.shape
+    bp = -(-B // ROWS) * ROWS
+    vp = -(-V // TILE) * TILE
     if park_vmem is None:
-        park_vmem = vp * 4 <= PARK_VMEM_LIMIT
-    args = (_pad_rows(logits.astype(jnp.float32), vp, NEG),
-            _pad_rows(gumbel.astype(jnp.float32), vp, 0.0),
-            k, p, min_p)
+        park_vmem = ROWS * vp * 4 <= PARK_VMEM_LIMIT
+    params = jnp.zeros((B, LANES), jnp.float32)
+    params = params.at[:, 0].set(k.astype(jnp.float32))
+    params = params.at[:, 1].set(p.astype(jnp.float32))
+    params = params.at[:, 2].set(min_p.astype(jnp.float32))
+    args = (_pad(logits.astype(jnp.float32), bp, vp, NEG),
+            _pad(gumbel.astype(jnp.float32), bp, vp, 0.0),
+            _pad(params, bp, LANES, 0.0))
     if with_lanes:
-        args += (_pad_rows(raw.astype(jnp.float32), vp, NEG),)
+        args += (_pad(raw.astype(jnp.float32), bp, vp, NEG),)
     outs = fused_sampling_tpu(*args, lp_k=lp_k, with_lanes=with_lanes,
                               park_vmem=bool(park_vmem),
                               interpret=interpret)
-    names = ["sampled", "greedy", "tau", "m", "l"]
-    if with_lanes:
-        names += ["m_raw", "l_raw"]
-        if lp_k > 0:
-            names += ["top_vals", "top_idx"]
-    return dict(zip(names, outs))
+    stats = outs[0][:B]
+    names = OUT_COLS if with_lanes else OUT_COLS[:5]
+    res = {nm: stats[:, i] for i, nm in enumerate(names)}
+    res["sampled"] = res["sampled"].astype(jnp.int32)
+    res["greedy"] = res["greedy"].astype(jnp.int32)
+    if with_lanes and lp_k > 0:
+        res["top_vals"] = outs[1][:B, :lp_k]
+        res["top_idx"] = outs[2][:B, :lp_k]
+    return res

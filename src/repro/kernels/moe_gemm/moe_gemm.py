@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _kernel(bexp_ref, x_ref, w_ref, o_ref):
@@ -52,7 +51,7 @@ def grouped_gemm_tpu(x, w, block_expert, *, block_t=128, block_f=128,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), x.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(block_expert, x, w)

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _kernel(states_ref, decay_ref, prev_ref, final_ref):
@@ -51,7 +50,7 @@ def ssd_state_scan_tpu(states, decay, *, interpret=False):
             jax.ShapeDtypeStruct((B, H, nc, N, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(states, decay)

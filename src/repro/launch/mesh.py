@@ -7,19 +7,22 @@ inter-pod DCN/ICI links).
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
+from jax.sharding import AxisType
+
 from repro.models.api import MeshAxes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(data: int = 1, model: int = 1):
     """Tiny mesh for CPU smoke tests (same axis names as production)."""
-    return compat.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_axes(mesh) -> MeshAxes:

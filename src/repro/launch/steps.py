@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat, optim
+from repro import optim
 from repro.configs import get_config
 from repro.distributed import sharding as shd
 from repro.launch import mesh as mesh_lib
@@ -45,7 +45,7 @@ class Cell:
                        donate_argnums=self.donate_argnums)
 
     def lower(self):
-        with compat.use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self.jitted().lower(*self.in_sds)
 
 

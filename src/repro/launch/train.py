@@ -1,7 +1,8 @@
 """Production training driver: builds the train cell for an (arch) on the
 production mesh and — on real hardware — runs the step loop with
-checkpoint/restart.  On this CPU container, --dry lowers + compiles only
-(see dryrun.py for the full matrix); --reduced actually trains a few steps.
+checkpoint/restart.  --dry lowers + compiles only on 512 virtual CPU
+devices (see dryrun.py for the full matrix); --reduced actually trains a
+few steps.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3_2_1b --dry
 """

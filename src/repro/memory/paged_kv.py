@@ -17,10 +17,10 @@ span reference exactly once (the SeqState is popped, so a second drop is a
 no-op), and MIGRATE moves a span's bytes once per span via ``adopt``, not
 once per sequence.
 
-On this CPU container "host" is NumPy and "device" is the jax array holding
-the engine's dense decode cache; on a real TPU deployment the same classes
-wrap pinned host buffers + device_put/device_get with async staging through
-the ring buffer (memory/buffers.py).
+"Host" is NumPy in host RAM and "device" is the engine's dense decode
+cache, jax arrays committed to the engine's chip; pages move with
+``device_put`` and async device-to-host copies staged through the ring
+buffer (memory/buffers.py).
 """
 from __future__ import annotations
 

@@ -23,7 +23,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.api import ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -222,7 +221,7 @@ def cache_update(cache, new, lengths, axes=None):
     owning position ``lengths`` writes one token (§Perf D1).
     """
     if axes is not None and axes.model is not None:
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if not mesh.empty and axes.model in mesh.axis_names:
             return _cache_update_dus(cache, new, lengths, axes, mesh)
     return _cache_update_dus_local(cache, new, lengths)
@@ -256,7 +255,7 @@ def _cache_update_dus(cache, new, lengths, axes, mesh):
     bspec = P(Bax, None, None, None)
     lspec = P(Bax)
 
-    @partial(compat.shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(cspec, bspec, lspec), out_specs=cspec,
              check_vma=False)
     def upd(c_l, n_l, len_l):

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.api import MeshAxes, ModelConfig
 from repro.models import layers
 
@@ -77,7 +76,7 @@ def _expert_mlp(cfg: ModelConfig, p, xs):
 
 def moe_fwd(cfg: ModelConfig, axes: MeshAxes, p, x):
     """Expert-parallel MoE over the current mesh. x: (B, S, D) -> (B, S, D), aux."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or axes.model is None or axes.model not in mesh.axis_names:
         y, aux = _moe_local(cfg, p, x)
     else:
@@ -86,7 +85,7 @@ def moe_fwd(cfg: ModelConfig, axes: MeshAxes, p, x):
 
         all_axes = tuple(mesh.axis_names)
 
-        @partial(compat.shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(bspec, P(None, None), espec, espec, espec),
                  out_specs=(bspec, P()), check_vma=False)
         def _sharded(xl, wg, w1, w3, w2):

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.api import MeshAxes, ModelConfig
 from repro.models import layers, moe as moe_lib, rglru, ssm as ssm_lib
 
@@ -265,7 +264,7 @@ def _stack_fwd(cfg, axes, stack, h, positions, hint, want_cache, remat,
 
 def _pin(axes: MeshAxes, h):
     """Keep the residual stream sharded (batch over DP axes, replicated TP)."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return h
     return jax.lax.with_sharding_constraint(h, P(axes.batch, None, None))
@@ -491,25 +490,37 @@ def decode_step_logits(cfg: ModelConfig, axes: MeshAxes, params, cache,
     return logits[:, 0, :], new_cache
 
 
+def _plane(tokens, chosen, vals, idx):
+    """One step's int32 plane row block: token ids as they are, log-probs
+    bitcast f32 -> int32.  The plane is int32, not f32, because a token id
+    bitcast to f32 is a denormal, and the TPU flushes f32 denormals to
+    zero on the way through the scan's output."""
+    as_i32 = lambda x: jax.lax.bitcast_convert_type(x.astype(jnp.float32),
+                                                    jnp.int32)
+    parts = [tokens.astype(jnp.int32)[:, None], as_i32(chosen)[:, None]]
+    if vals is not None:
+        parts += [as_i32(vals), idx.astype(jnp.int32)]
+    return jnp.concatenate(parts, axis=-1)
+
+
 def pack_logprob_block(tokens, logits, lp_k: int):
-    """Pack one decode step's (tokens, raw logits) into a single f32 row
+    """Pack one decode step's (tokens, raw logits) into a single int32 row
     block so the whole page still moves in ONE device->host transfer.
 
     Layout along the last axis (width 2 + 2*lp_k):
-      [0]                 tokens, int32 bitcast to f32 (exact round-trip)
-      [1]                 log-softmax(logits)[token] — chosen-token logprob
-      [2 : 2+K]           top-K logprob values (descending)
-      [2+K : 2+2K]        top-K token ids, int32 bitcast to f32
+      [0]                 tokens
+      [1]                 log-softmax(logits)[token] — chosen-token
+                          logprob, f32 bitcast to int32
+      [2 : 2+K]           top-K logprob values (descending), f32 bitcast
+      [2+K : 2+2K]        top-K token ids
     Unpacked host-side by ``unpack_logprob_block``."""
     lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     chosen = jnp.take_along_axis(lp, tokens[:, None].astype(jnp.int32),
-                                 axis=1)
-    parts = [jax.lax.bitcast_convert_type(tokens.astype(jnp.int32),
-                                          jnp.float32)[:, None], chosen]
+                                 axis=1)[:, 0]
+    vals = idx = None
     if lp_k > 0:
         vals, idx = jax.lax.top_k(lp, lp_k)
-        parts += [vals, jax.lax.bitcast_convert_type(idx, jnp.float32)]
-    return jnp.concatenate(parts, axis=-1)
+    return _plane(tokens, chosen, vals, idx)
 
 
 def pack_plane_from_lanes(tokens, lanes):
@@ -518,14 +529,8 @@ def pack_plane_from_lanes(tokens, lanes):
     so the sampled megastep reuses the single fused-sampling pass for the
     transfer plane instead of paying a second full-vocab log_softmax +
     top_k.  Layout-identical to ``pack_logprob_block``."""
-    parts = [jax.lax.bitcast_convert_type(tokens.astype(jnp.int32),
-                                          jnp.float32)[:, None],
-             lanes["chosen_lp"][:, None]]
-    if lanes["top_vals"] is not None:
-        parts += [lanes["top_vals"],
-                  jax.lax.bitcast_convert_type(
-                      lanes["top_idx"].astype(jnp.int32), jnp.float32)]
-    return jnp.concatenate(parts, axis=-1)
+    return _plane(tokens, lanes["chosen_lp"], lanes["top_vals"],
+                  lanes["top_idx"])
 
 
 def unpack_logprob_block(block_np):
@@ -534,13 +539,13 @@ def unpack_logprob_block(block_np):
     topk_vals (steps,B,K) f32 | None, topk_ids (steps,B,K) i32 | None)."""
     import numpy as np
     K = (block_np.shape[-1] - 2) // 2
-    tokens = np.ascontiguousarray(block_np[..., 0]).view(np.int32)
-    chosen = block_np[..., 1]
+    as_f32 = lambda x: np.ascontiguousarray(x).view(np.float32)
+    tokens = block_np[..., 0]
+    chosen = as_f32(block_np[..., 1])
     if K == 0:
         return tokens, chosen, None, None
-    vals = block_np[..., 2:2 + K]
-    ids = np.ascontiguousarray(block_np[..., 2 + K:]).view(np.int32)
-    return tokens, chosen, vals, ids
+    return tokens, chosen, as_f32(block_np[..., 2:2 + K]), \
+        block_np[..., 2 + K:]
 
 
 def decode_page(cfg: ModelConfig, axes: MeshAxes, params, cache, tokens,
@@ -572,7 +577,7 @@ def decode_page(cfg: ModelConfig, axes: MeshAxes, params, cache, tokens,
 
     With ``lp_k`` set (0 = chosen-token only, K > 0 = also the top-K
     alternatives) each step's output row is the packed
-    ``pack_logprob_block`` plane — (steps, B, 2+2K) f32 — built from the
+    ``pack_logprob_block`` plane — (steps, B, 2+2K) int32 — built from the
     RAW (pre-sampling-pipeline) model logits, so logprobs ride the
     page's one transfer and report pre-filter values even under
     top-k/top-p sampling.

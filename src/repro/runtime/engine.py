@@ -84,13 +84,13 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict, deque
+from functools import partial
 from typing import Deque, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro import sampling as smp
 from repro.core.backend import validate_backend
 from repro.core.coroutine import Phase, SequenceCoroutine, Status
@@ -158,6 +158,34 @@ def _np_log_softmax(x: np.ndarray) -> np.ndarray:
     return (x - m) - np.log(e.sum(axis=-1, keepdims=True))
 
 
+def _prefill_logits(cfg: ModelConfig, axes: MeshAxes, params, tokens, last):
+    """Prompt forward over a (B, S) bucket: the logits at each row's last
+    prompt position ``last`` and the full-prompt caches."""
+    h, _, caches = T._backbone(cfg, axes, params, {"tokens": tokens}, None,
+                               True, False)               # h final-normed
+    hl = jnp.take_along_axis(h, last[:, None, None].astype(
+        jnp.int32).repeat(h.shape[-1], -1), axis=1)
+    return T.logits_fn(cfg, params, hl), caches
+
+
+def _install_scatter(cache, tokens, lengths, idx, upd, tok, ln):
+    """Write n staged slots (cache leaves, last token, length) at once."""
+    new = dict(cache)
+    for nm, u in upd.items():
+        new[nm] = cache[nm].at[:, idx].set(u)
+    return new, tokens.at[idx].set(tok), lengths.at[idx].set(ln)
+
+
+def _gather_blob(names, cache, slots, pos):
+    """Every dirty slot's window of every leaf, (n, 1) slots x (n, W)
+    positions, as ONE (L, n, W, F_total) blob."""
+    parts = []
+    for nm in names:
+        seg = cache[nm][:, slots, pos]                  # (L, n, W, *trail)
+        parts.append(seg.reshape(seg.shape[:3] + (-1,)))
+    return jnp.concatenate(parts, axis=-1)
+
+
 class NodeEngine:
     def __init__(self, cfg: ModelConfig, *, node_id: int = 0,
                  max_active: int = 8, max_len: int = 256,
@@ -169,7 +197,7 @@ class NodeEngine:
                  restore_ring_bytes: Optional[int] = None, seed: int = 0,
                  faults: Optional[NodeFaults] = None,
                  retry_policy: Optional[RetryPolicy] = None,
-                 enable_prefix: bool = True):
+                 enable_prefix: bool = True, device=None):
         assert cfg.family in ("dense", "moe") and cfg.sliding_window == 0, \
             "mini-engine supports dense/moe caches; see cluster sim for rest"
         self.cfg = cfg
@@ -181,8 +209,15 @@ class NodeEngine:
         self.page_size = page_size
         self.fused = fused
         self.overlap = overlap
+        # the chip this engine runs on (default: the first device).  Every
+        # array it owns is committed there, so jitted steps run there and
+        # several engines in one process each keep to their own chip.
+        self.device = device if device is not None else jax.devices()[0]
 
-        self.params = T.init_params(cfg, jax.random.PRNGKey(seed))
+        with jax.default_device(self.device):
+            self.params = self._put(T.init_params(cfg,
+                                                  jax.random.PRNGKey(seed)))
+            self.cache = self._put(T.init_cache(cfg, max_active, max_len))
         self.host_store = HostKVStore(page_size, enable_prefix=enable_prefix)
         total_pages = device_pages or (max_active * max_len // page_size * 2)
         self.allocator = PageAllocator(total_pages, page_size)
@@ -197,10 +232,9 @@ class NodeEngine:
         self.straggler_steps = 0        # decode steps run under a straggler
         self.abandoned_blobs = 0        # staged blobs lost to dead-letters
 
-        # device slot arrays
-        self.cache = T.init_cache(cfg, max_active, max_len)
-        self.tokens = jnp.zeros((max_active,), jnp.int32)
-        self.lengths = jnp.zeros((max_active,), jnp.int32)
+        # device slot arrays (the cache was built with the params above)
+        self.tokens = self._put(np.zeros((max_active,), np.int32))
+        self.lengths = self._put(np.zeros((max_active,), np.int32))
         self.slot_owner: List[Optional[int]] = [None] * max_active
         self.synced_len: Dict[int, int] = {}
 
@@ -210,12 +244,12 @@ class NodeEngine:
         self._sp_host = smp.pack_params([smp.SamplingParams()] * max_active,
                                         list(range(max_active)))
         self._sp_dev: Optional[Dict] = None
-        self._sample_state = {
-            "base_key": jnp.zeros((max_active, 2), jnp.uint32),
-            "gen_count": jnp.zeros((max_active,), jnp.int32),
-            "counts": jnp.zeros((max_active, V), jnp.int32),
-            "prompt_counts": jnp.zeros((max_active, V), jnp.int32),
-        }
+        self._sample_state = self._put({
+            "base_key": np.zeros((max_active, 2), np.uint32),
+            "gen_count": np.zeros((max_active,), np.int32),
+            "counts": np.zeros((max_active, V), np.int32),
+            "prompt_counts": np.zeros((max_active, V), np.int32),
+        })
         # slot installs stage their re-derived sampling state here (host
         # numpy, keyed by slot so a re-install overwrites) and the next
         # sampled decode_page scatters everything in one batch — per-slot
@@ -297,6 +331,10 @@ class NodeEngine:
         self.restore_wait_s = 0.0       # h2d restore transfer time (all paths)
         self.restore_stage_hidden_s = 0.0   # portion hidden behind decode
         self.restore_staged_bytes = 0   # cumulative prefetched bytes
+
+    def _put(self, tree):
+        """Host -> this engine's device (committed)."""
+        return jax.device_put(tree, self.device)
 
     # ------------------------------------------------------------- protocol
     def clock(self) -> float:
@@ -381,7 +419,8 @@ class NodeEngine:
                 continue
             leaf = self.cache[name]
             a = self._pad_slot_arr(arr, leaf)
-            self.cache[name] = leaf.at[:, s].set(jnp.asarray(a, leaf.dtype))
+            self.cache[name] = leaf.at[:, s].set(
+                self._put(np.asarray(a, leaf.dtype)))
         self.tokens = self.tokens.at[s].set(last_token)
         self.lengths = self.lengths.at[s].set(length)
 
@@ -415,19 +454,13 @@ class NodeEngine:
                                leaf.dtype) for _, slices, _, _ in full]
             upds[name] = np.stack(rows, axis=1)     # (L, n, S, *trail)
 
-        def make():
-            def _apply(cache, tokens, lengths, idx, upd, tok, ln):
-                new = dict(cache)
-                for nm, u in upd.items():
-                    new[nm] = cache[nm].at[:, idx].set(u)
-                return new, tokens.at[idx].set(tok), lengths.at[idx].set(ln)
-            return jax.jit(_apply, donate_argnums=(0, 1, 2))
-        fn = _lru_get(self._install_cache, n, _INSTALL_JIT_CAP, make)
+        fn = _lru_get(self._install_cache, n, _INSTALL_JIT_CAP,
+                      lambda: jax.jit(_install_scatter,
+                                      donate_argnums=(0, 1, 2)))
         try:
             out = self.transfer("install", lambda: fn(
-                self.cache, self.tokens, self.lengths, jnp.asarray(slot_idx),
-                {k: jnp.asarray(v) for k, v in upds.items()},
-                jnp.asarray(toks), jnp.asarray(lens)))
+                self.cache, self.tokens, self.lengths,
+                *self._put((slot_idx, upds, toks, lens))))
         except TransferDeadLetter:
             # the staged installs are lost and their slots hold stale
             # data; the scheduler sees ``dead_lettered`` and escalates to
@@ -491,15 +524,15 @@ class NodeEngine:
             return jax.jit(_apply, donate_argnums=(0,))
         fn = _lru_get(self._flush_cache, n, 8, make)
         self._sample_state = fn(self._sample_state,
-                                jnp.asarray(slots, jnp.int32), *cols)
+                                *self._put((np.asarray(slots, np.int32),
+                                            *cols)))
 
     def _sp_device(self) -> Dict:
         """Packed per-slot sampling params as device arrays (cached until
         a slot install dirties the host mirror)."""
         if self._sp_dev is None:
-            self._sp_dev = {k: jnp.asarray(v)
-                            for k, v in self._sp_host.items()
-                            if k != "seed"}
+            self._sp_dev = self._put({k: v for k, v in self._sp_host.items()
+                                      if k != "seed"})
         return self._sp_dev
 
     def reconfigure_partition(self, co: SequenceCoroutine, group: List[int]):
@@ -561,7 +594,7 @@ class NodeEngine:
         rem = np.zeros((self.max_active,), np.int32)
         for co in active:
             rem[co.slot] = co.remaining
-        rem_j = jnp.asarray(rem)
+        rem_j = self._put(rem)
         sp = self._sp_device() if sampled else None
         if sampled:
             self._flush_pending_sampling()
@@ -738,11 +771,12 @@ class NodeEngine:
                     upd_tok.append((s, tok))
                     upd_len.append((s, co.length))
             if upd_tok:
-                idx = jnp.array([s for s, _ in upd_tok])
-                self.tokens = self.tokens.at[idx].set(
-                    jnp.array([t for _, t in upd_tok], jnp.int32))
-                self.lengths = self.lengths.at[idx].set(
-                    jnp.array([l for _, l in upd_len], jnp.int32))
+                idx, tok, ln = self._put((
+                    np.array([s for s, _ in upd_tok], np.int32),
+                    np.array([t for _, t in upd_tok], np.int32),
+                    np.array([l for _, l in upd_len], np.int32)))
+                self.tokens = self.tokens.at[idx].set(tok)
+                self.lengths = self.lengths.at[idx].set(ln)
             if all(c.remaining == 0 for c in active):
                 break
 
@@ -757,17 +791,9 @@ class NodeEngine:
         """Jitted batched dirty-window gather -> one (L, n, W, F_total)
         blob, bucketed to (pow2 slots, pow2 window) so steady-state page
         syncs reuse a handful of executables."""
-        names = [m[0] for m in self._blob_metas]
-
-        def make():
-            def _g(cache, slots, pos):
-                parts = []
-                for nm in names:
-                    seg = cache[nm][:, slots, pos]      # (L, n, W, *trail)
-                    parts.append(seg.reshape(seg.shape[0], n, W, -1))
-                return jnp.concatenate(parts, axis=-1)
-            return jax.jit(_g)
-        return _lru_get(self._gather_cache, (n, W), _GATHER_JIT_CAP, make)
+        names = tuple(m[0] for m in self._blob_metas)
+        return _lru_get(self._gather_cache, (n, W), _GATHER_JIT_CAP,
+                        lambda: jax.jit(partial(_gather_blob, names)))
 
     def _gather_dirty(self, active) -> Optional[_InFlightSync]:
         """Issue the batched gather of every dirty slot's [synced, length)
@@ -800,7 +826,7 @@ class NodeEngine:
         pos = np.minimum(starts[:, None] + np.arange(W_pad)[None],
                          self.max_len - 1).astype(np.int32)
         blob = self._get_gather(n_pad, W_pad)(
-            self.cache, jnp.asarray(slots), jnp.asarray(pos))
+            self.cache, *self._put((slots, pos)))
         snaps = []
         for co, start, first in todo:
             snaps.append((co.seq_id, start, co.length - start, first))
@@ -830,8 +856,7 @@ class NodeEngine:
             self.drain_appends()
         if self.ring.can_fit(ent.nbytes):
             try:
-                self.transfer("stage",
-                              lambda: compat.copy_to_host_async(ent.blob))
+                self.transfer("stage", ent.blob.copy_to_host_async)
             except TransferDeadLetter:
                 self._abandon_blob(ent)
                 return
@@ -922,8 +947,7 @@ class NodeEngine:
             self.restore_stalls += 1
             return False
         try:
-            dev = self.transfer("restore", lambda: {
-                k: jax.device_put(v) for k, v in slices.items()})
+            dev = self.transfer("restore", lambda: self._put(slices))
         except TransferDeadLetter:
             return False
         self.restore_ring.reserve(f"restore{co.seq_id}", nbytes)
@@ -1046,19 +1070,11 @@ class NodeEngine:
             for i, c in enumerate(fresh):
                 toks[i, : c.prompt_len] = c.prompt[:]
                 last_idx[i] = c.prompt_len - 1
-            def make():
-                def _prefill_impl(params, tokens, last):
-                    h, _, caches = T._backbone(self.cfg, self.axes, params,
-                                               {"tokens": tokens}, None,
-                                               True, False)  # h final-normed
-                    hl = jnp.take_along_axis(h, last[:, None, None].astype(
-                        jnp.int32).repeat(h.shape[-1], -1), axis=1)
-                    logits = T.logits_fn(self.cfg, params, hl)
-                    return logits, caches
-                return jax.jit(_prefill_impl)
-            fn = _lru_get(self._prefill_cache, (B, S), _PREFILL_JIT_CAP, make)
-            fresh_logits, cache = fn(self.params, jnp.asarray(toks),
-                                     jnp.asarray(last_idx))
+            fn = _lru_get(self._prefill_cache, (B, S), _PREFILL_JIT_CAP,
+                          lambda: jax.jit(partial(_prefill_logits, self.cfg,
+                                                  self.axes)))
+            fresh_logits, cache = fn(self.params,
+                                     *self._put((toks, last_idx)))
             nf = len(fresh)
             # batched host-checkpoint gather: flatten every leaf's first-nf
             # rows into ONE (L, nf, W, F_total) blob and move it with a
@@ -1104,12 +1120,13 @@ class NodeEngine:
             pl = lead.prompt_len
             self.host_store.attach_shared(lead.seq_id, chain)
             S = max(_pow2(pl), 8)
-            dense = T.init_cache(self.cfg, 1, S)
+            with jax.default_device(self.device):
+                dense = self._put(T.init_cache(self.cfg, 1, S))
             for name in names:
                 seg = np.concatenate([nd.pages[name] for nd in chain],
                                      axis=1)        # (L, m, *trail)
                 dense[name] = dense[name].at[:, :, :m].set(
-                    jnp.asarray(seg)[:, None])
+                    self._put(seg)[:, None])
             if self._decode_logits is None:
                 self._decode_logits = jax.jit(
                     lambda p, c, t, l: T.decode_step_logits(
@@ -1119,8 +1136,8 @@ class NodeEngine:
             for t in range(m, pl):
                 row, dense = self._decode_logits(
                     self.params, dense,
-                    jnp.asarray([lead.prompt[t]], jnp.int32),
-                    jnp.asarray([t], jnp.int32))
+                    *self._put((np.asarray([lead.prompt[t]], np.int32),
+                                np.asarray([t], np.int32))))
             slices = {name: self._to_host(dense[name][:, 0, m:pl])
                       for name in names}
             self.host_store.append_tokens(lead.seq_id, slices, m)
@@ -1158,11 +1175,10 @@ class NodeEngine:
             flags = smp.flags_for([c.sampling for c in cos],
                                   T.padded_vocab(self.cfg))
             draw = self._get_prefill_sampler(n, flags)
-            first = self._to_host(draw(
-                logits2d, jnp.asarray(st["prompt_counts"]),
-                jnp.asarray(st["counts"]),
-                {k: jnp.asarray(sp[k]) for k in _SAMPLE_ROW_KEYS},
-                jnp.asarray(smp.base_keys_host(st["seed"]))))
+            first = self._to_host(draw(logits2d, *self._put((
+                st["prompt_counts"], st["counts"],
+                {k: sp[k] for k in _SAMPLE_ROW_KEYS},
+                smp.base_keys_host(st["seed"])))))
         else:
             logits_np = self._to_host(logits2d)
             first = np.argmax(logits_np, axis=-1)

@@ -27,11 +27,10 @@ are wasteful to decide on device every step, so the engine derives them
 ON THE HOST from the active batch's SamplingParams and bakes them into
 the megastep executable (they are part of the jit cache key):
 
-* ``backend`` — ``"pallas"`` routes the filter + draw through the fused
-  single-pass kernel (``repro.kernels.fused_sampling``); ``"xla"`` is
-  the shared-sort fallback for platforms where Pallas interpret mode is
-  slow (CPU CI).  ``"pallas_interpret"`` runs the kernel interpreted
-  (tests).
+* ``backend`` — ``"pallas"`` (TPU) routes the filter + draw through the
+  fused single-pass kernel (``repro.kernels.fused_sampling``), compiled;
+  ``"xla"`` is the shared-sort path for every other platform (CPU CI).
+  The platform alone decides (``default_backend``).
 * ``pen`` — False drops the penalty ops AND the per-step (B, V) count
   updates from the scan when no active slot enables a penalty.
 * ``kc`` — the sort tier of ``processors.joint_threshold``: 0 full
@@ -60,7 +59,6 @@ no extra device state movement.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -73,7 +71,7 @@ from repro.sampling.processors import apply_penalties, apply_temperature
 @dataclasses.dataclass(frozen=True)
 class SampleFlags:
     """Static (host-decided, jit-keyed) execution plan for one megastep."""
-    backend: str = "xla"     # "xla" | "pallas" | "pallas_interpret"
+    backend: str = "xla"     # "xla" | "pallas"
     pen: bool = True         # any penalty enabled in the active batch
     kc: int = 0              # sort tier: 0 full, >0 top-kc, -1 sortless
     mixed: bool = True       # any greedy (temperature <= 0) row present
@@ -84,11 +82,7 @@ DEFAULT_FLAGS = SampleFlags()
 
 
 def default_backend() -> str:
-    """Kernel on real TPUs, shared-sort XLA everywhere else.  Override
-    with REPRO_SAMPLING_BACKEND=xla|pallas|pallas_interpret."""
-    env = os.environ.get("REPRO_SAMPLING_BACKEND")
-    if env:
-        return env
+    """The compiled kernel on TPUs, shared-sort XLA everywhere else."""
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
@@ -243,7 +237,7 @@ def _sample_impl(logits, counts_full, counts_gen, sp, keys,
     reports (PR 3 contract: logprobs are pre-filter); lanes are computed
     in the same kernel invocation on the pallas path, or with one
     log_softmax + lax.top_k on the XLA path."""
-    if flags.backend in ("pallas", "pallas_interpret"):
+    if flags.backend == "pallas":
         from repro.kernels.fused_sampling.ops import fused_sample
 
         proc = _processed(logits, counts_full, counts_gen, sp, flags)
@@ -251,8 +245,7 @@ def _sample_impl(logits, counts_full, counts_gen, sp, keys,
         out = fused_sample(proc, gumbel, sp["top_k"], sp["top_p"],
                            sp["min_p"], raw=raw,
                            lp_k=0 if lp_k is None else max(lp_k, 0),
-                           with_lanes=lp_k is not None,
-                           interpret=flags.backend == "pallas_interpret")
+                           with_lanes=lp_k is not None)
         tokens = (jnp.where(sp["temperature"] <= 0.0, out["greedy"],
                             out["sampled"]) if flags.mixed
                   else out["sampled"]).astype(jnp.int32)

@@ -287,6 +287,26 @@ def test_logprobs_fused_matches_looped(rng):
                                        [x for _, x in rb], atol=2e-4)
 
 
+def test_logprob_plane_is_int32():
+    """The plane is int32 with the log-probs bitcast into it: token ids
+    bitcast into an f32 plane are denormals, which the TPU flushes to
+    zero, so every id would come back as 0 there."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import transformer as T
+    logits = jax.random.normal(jax.random.PRNGKey(0), (4, 1000))
+    tokens = jnp.asarray([3, 999, 500, 7], jnp.int32)
+    block = T.pack_logprob_block(tokens, logits, 3)
+    assert block.dtype == jnp.int32
+    toks, chosen, vals, ids = T.unpack_logprob_block(np.asarray(block)[None])
+    lp = jax.nn.log_softmax(logits, axis=-1)
+    ref_vals, ref_ids = jax.lax.top_k(lp, 3)
+    np.testing.assert_array_equal(toks[0], tokens)
+    np.testing.assert_array_equal(ids[0], ref_ids)
+    np.testing.assert_array_equal(vals[0], ref_vals)
+    np.testing.assert_array_equal(chosen[0], lp[jnp.arange(4), tokens])
+
+
 def test_logprobs_do_not_perturb_token_stream(rng):
     cfg = reduced_config("llama3_2_1b")
     prompts = [list(rng.integers(2, cfg.vocab_size, 5)) for _ in range(3)]

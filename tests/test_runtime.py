@@ -39,6 +39,40 @@ def test_batch_api_order_and_completion(rng):
                for i, r in enumerate(bo.results))
 
 
+def test_build_master_commits_each_engine_to_its_device(rng):
+    """``build_master`` puts engine i on ``devices[i % len(devices)]`` and
+    every array an engine owns stays committed there through a batch."""
+    from repro.launch.serve import build_master
+    cfg = reduced_config("qwen2_0_5b")
+    devs = jax.devices()
+    master, engines = build_master(cfg, nodes=2, max_active=2, max_len=64,
+                                   page_size=8, devices=devs)
+    reqs = [BatchRequest(custom_id=f"r{i}",
+                         prompt=list(rng.integers(2, 100, 6)), max_tokens=5)
+            for i in range(4)]
+    assert master.run(master.submit(reqs)).request_counts["failed"] == 0
+    for i, e in enumerate(engines):
+        assert e.device == devs[i % len(devs)]
+        owned = jax.tree.leaves((e.params, e.cache, e.tokens, e.lengths,
+                                 e._sample_state))
+        assert all(a.committed and a.devices() == {e.device} for a in owned)
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env):
+    """The cache goes to $JAX_COMPILATION_CACHE_DIR when it is set, else
+    to one fixed path inside the checkout that git ignores."""
+    from repro.launch import compile_cache
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = compile_cache.CHECKOUT_CACHE_DIR.parent
+        assert compile_cache.cache_dir() == str(repo / ".jax_cache")
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+        assert compile_cache.cache_dir() == str(tmp_path / env)
+
+
 def test_checkpoint_roundtrip(tmp_path, rng):
     cfg = reduced_config("qwen2_0_5b")
     params = T.init_params(cfg, jax.random.PRNGKey(0))

@@ -1,0 +1,9 @@
+"""Peak device memory in use over the run up to the end of the window
+(``memory_stats()["peak_bytes_in_use"]`` of the fullest chip, read
+before the reference check runs), in GiB."""
+
+
+def read(ctx):
+    if not ctx.memory_peak_bytes:
+        return None
+    return ctx.memory_peak_bytes / 2 ** 30

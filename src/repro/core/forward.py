@@ -13,12 +13,14 @@ and is what benchmarks/expert_batching.py measures (Fig. 2b reproduction).
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import OrderedDict
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.models import layers, moe as moe_lib, transformer as T
 from repro.models.api import MeshAxes, ModelConfig
@@ -30,16 +32,30 @@ from repro.models.api import MeshAxes, ModelConfig
 _PAGE_JIT_CAP = 16
 
 
-def _lru_get(cache: "OrderedDict", key, cap: int, make):
-    """Fetch-or-build `key` in an OrderedDict LRU bounded to `cap`."""
+def _lru_get(cache: "OrderedDict", key, cap: int, make, owner):
+    """Fetch-or-build `key` in an OrderedDict LRU bounded to `cap`.
+
+    A newly built executable comes back wrapped for its first call, which
+    traces, lowers and compiles it (or loads it from the compilation
+    cache): that call runs in an ``engine.node.compile`` span keyed by
+    `key` and advances ``owner.jit_builds`` and ``owner.jit_build_s``."""
     fn = cache.get(key)
-    if fn is None:
-        fn = cache[key] = make()
-    else:
+    if fn is not None:
         cache.move_to_end(key)
+        return fn
+    fn = cache[key] = make()
     while len(cache) > cap:
         cache.popitem(last=False)
-    return fn
+    return partial(_first_call, fn, key, owner)
+
+
+def _first_call(fn, key, owner, *args, **kwargs):
+    t0 = time.perf_counter()
+    with TraceAnnotation("engine.node.compile", key=key):
+        out = fn(*args, **kwargs)
+    owner.jit_builds += 1
+    owner.jit_build_s += time.perf_counter() - t0
+    return out
 
 
 def _sub_slices(B: int, n_sub: int) -> List[slice]:
@@ -66,11 +82,16 @@ class ModuleRuntime:
     coroutine units (option (a) fuses them; option (c) per-expert is noted
     as memory-prohibitive by the paper)."""
 
-    def __init__(self, cfg: ModelConfig, axes: MeshAxes, params):
+    def __init__(self, cfg: ModelConfig, axes: MeshAxes, params,
+                 owner=None):
         assert cfg.family in ("moe", "dense"), cfg.family
         self.cfg = cfg
         self.axes = axes
         self.params = params
+        # page-executable builds are counted on `owner` (the engine)
+        self.jit_builds = 0
+        self.jit_build_s = 0.0
+        self.owner = owner if owner is not None else self
         # pre-split stacked layer params -> list of per-layer trees
         L = cfg.num_layers
         self.layer_params = [jax.tree.map(lambda x, i=i: x[i],
@@ -193,7 +214,7 @@ class ModuleRuntime:
                                               n_sub=n_sub,
                                               sampled=sampling is not None,
                                               lp_k=lp_k, flags=flags),
-                                      donate_argnums=(0,)))
+                                      donate_argnums=(0,)), self.owner)
         if sampling is None:
             return fn(cache, tokens, lengths, remaining)
         sp, state = sampling

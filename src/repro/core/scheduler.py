@@ -112,6 +112,8 @@ import time
 from typing import (Callable, Dict, Iterator, List, Optional, Sequence,
                     Tuple, Type, Union)
 
+from jax.profiler import TraceAnnotation
+
 from repro.core import primitives as prim
 from repro.core.backend import validate_backend
 from repro.core.coroutine import Phase, SequenceCoroutine, Status
@@ -125,6 +127,8 @@ from repro.sampling.params import SamplingParams, derive_fork_seed
 logger = logging.getLogger(__name__)
 
 _TICK = "tick"      # payload marking the round-seeding REFILL event
+# profiler span of each handler: engine.sched.<event kind, lower case>
+_SPANS = {k: f"engine.sched.{k.name.lower()}" for k in EventKind}
 
 
 @dataclasses.dataclass
@@ -1025,7 +1029,8 @@ class CoroutineScheduler:
         handler = self._handlers.get(ev.kind)
         if handler is None:
             raise KeyError(f"no handler registered for {ev.kind!r}")
-        handler(self, ev)
+        with TraceAnnotation(_SPANS[ev.kind], node=ev.node):
+            handler(self, ev)
         out, self._outbox = self._outbox, []
         return out
 
@@ -1290,18 +1295,24 @@ class CoroutineScheduler:
             yield from self._escalate_dead_letters()
 
     def _step_events(self) -> Iterator[RuntimeRecord]:
-        if self._t0 is None:
-            self._t0 = min((e.clock() for e in self.engines), default=0.0)
-        self._advance_faults()
-        self._collect_heartbeats()
-        # Externally-pushed events (NODE_FAILURE from a health monitor,
-        # custom policy work) drain BEFORE this round's work is seeded —
-        # a failed node must not be refilled/decoded one last time just
-        # because NODE_FAILURE's dispatch priority trails the others.
-        yield from self._drain_queue()
-        self._seed_round()
-        yield from self._drain_queue()
-        self.ticks += 1
+        """One round, in an ``engine.sched.round`` span (which, for a
+        consumer of ``events()``, also covers its handling of the
+        round's records)."""
+        with TraceAnnotation("engine.sched.round", tick=self.ticks):
+            if self._t0 is None:
+                self._t0 = min((e.clock() for e in self.engines),
+                               default=0.0)
+            self._advance_faults()
+            self._collect_heartbeats()
+            # Externally-pushed events (NODE_FAILURE from a health
+            # monitor, custom policy work) drain BEFORE this round's work
+            # is seeded — a failed node must not be refilled/decoded one
+            # last time just because NODE_FAILURE's dispatch priority
+            # trails the others.
+            yield from self._drain_queue()
+            self._seed_round()
+            yield from self._drain_queue()
+            self.ticks += 1
 
     def step(self) -> List[RuntimeRecord]:
         """One scheduler round: seed per-node work, then drain the event
@@ -1367,6 +1378,13 @@ class CoroutineScheduler:
         for i, e in enumerate(self.engines):
             stats[f"node{i}"] = {"counts": dict(e.stats.counts),
                                  "bytes": dict(e.stats.bytes_moved)}
+        # the engines' host-side costs, which the engine.node.* profiler
+        # spans break down: slot installs and executable builds
+        engine = {"install_s": 0.0, "slots_installed": 0,
+                  "jit_builds": 0, "jit_build_s": 0.0}
+        for e in self._all_engines:
+            for k in engine:
+                engine[k] += getattr(e, k, 0)
         xfer = {"retries": 0, "timeouts": 0, "dead_letters": 0}
         for e in self._all_engines:
             for k in xfer:
@@ -1433,6 +1451,7 @@ class CoroutineScheduler:
                       + self.retired - self.hedges_resolved),
             "mean_sct_s": sum(scts) / len(scts) if scts else 0.0,
             "primitives": stats,
+            "engine": engine,
             "prefix": prefix,
             "robustness": robustness,
             "log_tail": self.log[-20:],

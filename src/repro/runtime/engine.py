@@ -90,6 +90,7 @@ from typing import Deque, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import sampling as smp
 from repro.core.backend import validate_backend
@@ -265,7 +266,12 @@ class NodeEngine:
         self._decode_logits = None      # lazy: looped-baseline logprob path
         self._megastep_cache: "OrderedDict[tuple, object]" = OrderedDict()
         self._prefill_cache: "OrderedDict[tuple, object]" = OrderedDict()
-        self.module_rt = (ModuleRuntime(cfg, self.axes, self.params)
+        # executables built by the jit LRUs, and the host wall time of
+        # their first call (trace, lower, compile or cache load)
+        self.jit_builds = 0
+        self.jit_build_s = 0.0
+        self.module_rt = (ModuleRuntime(cfg, self.axes, self.params,
+                                        owner=self)
                           if module_granularity else None)
         self.b_attn = b_attn or max_active
         self.decode_steps = 0
@@ -300,16 +306,16 @@ class NodeEngine:
                                _HOST_LINK_BW)
         self._sync_tag = 0
         self.sync_stages = 0        # async-staged blobs
-        self.sync_drains = 0        # blobs landed in the host store
         self.sync_stalls = 0        # ring-full fallbacks to synchronous drain
         self.sync_wait_s = 0.0      # wall time blocked materializing blobs
-        self.staged_bytes = 0       # cumulative bytes through the ring
 
         # ---- batched slot installs (COMBINE/refill) -----------------------
         # slot -> (cache slices, last_token, length), flushed in one jitted
         # multi-slot scatter before the next consumer of device state
         self._pending_install: "OrderedDict[int, tuple]" = OrderedDict()
         self._install_cache: "OrderedDict[int, object]" = OrderedDict()
+        self.install_s = 0.0            # host wall time flushing installs
+        self.slots_installed = 0        # slots written (not pow2 padding)
 
         # ---- staged h2d restores (stage_restore / take_restore) -----------
         # the host→device mirror of the d2h sync pipeline: a suspended
@@ -429,11 +435,24 @@ class NodeEngine:
         (slot counts pow2-padded by repeating the first entry — duplicate
         identical updates are harmless — so slot churn reuses a handful
         of executables).  Called before anything reads device slot state
-        (decode, extract, the sync gather)."""
+        (decode, extract, the sync gather).  Runs in an
+        ``engine.node.install`` span; its wall time advances
+        ``install_s`` and the slots it writes ``slots_installed``."""
         if not self._pending_install:
             return
-        items = list(self._pending_install.items())
-        self._pending_install.clear()
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.node.install",
+                             seqs=[self.slot_owner[s]
+                                   for s in self._pending_install]):
+            items = list(self._pending_install.items())
+            self._pending_install.clear()
+            self.slots_installed += self._install_items(items)
+            del items       # freeing the staged host copies is install work
+        self.install_s += time.perf_counter() - t0
+
+    def _install_items(self, items) -> int:
+        """Write ``items`` (slot, staged install) to the device; returns
+        the slots written."""
         names = [m[0] for m in self._blob_metas]
         full, partial = [], []
         for s, (slices, tok, ln) in items:
@@ -442,7 +461,7 @@ class NodeEngine:
         for s, slices, tok, ln in partial:
             self._install_now(s, slices, tok, ln)
         if not full:
-            return
+            return len(partial)
         n = _pow2(len(full))
         full += [full[0]] * (n - len(full))
         slot_idx = np.array([s for s, *_ in full], np.int32)
@@ -456,7 +475,7 @@ class NodeEngine:
 
         fn = _lru_get(self._install_cache, n, _INSTALL_JIT_CAP,
                       lambda: jax.jit(_install_scatter,
-                                      donate_argnums=(0, 1, 2)))
+                                      donate_argnums=(0, 1, 2)), self)
         try:
             out = self.transfer("install", lambda: fn(
                 self.cache, self.tokens, self.lengths,
@@ -466,8 +485,9 @@ class NodeEngine:
             # data; the scheduler sees ``dead_lettered`` and escalates to
             # NODE_FAILURE, whose recovery recomputes the affected
             # sequences from their prompts
-            return
+            return len(partial)
         self.cache, self.tokens, self.lengths = out
+        return len(items)
 
     def _install_sampling(self, co: SequenceCoroutine):
         """Bind a slot's sampling params + re-derived device state.
@@ -522,7 +542,7 @@ class NodeEngine:
                         "prompt_counts":
                             state["prompt_counts"].at[sl].set(pc)}
             return jax.jit(_apply, donate_argnums=(0,))
-        fn = _lru_get(self._flush_cache, n, 8, make)
+        fn = _lru_get(self._flush_cache, n, 8, make, self)
         self._sample_state = fn(self._sample_state,
                                 *self._put((np.asarray(slots, np.int32),
                                             *cols)))
@@ -574,14 +594,20 @@ class NodeEngine:
         sampled = any(not c.sampling.is_greedy_default for c in active)
         want_lp = [c for c in active if c.logprobs]
         lp_k = max(c.top_logprobs for c in want_lp) if want_lp else None
-        # static sampling plan (backend / penalty skip / sort tier) decided
-        # host-side from the active params — part of the jit cache key
-        flags = (smp.flags_for([c.sampling for c in active],
-                               T.padded_vocab(self.cfg)) if sampled else None)
         if not self.fused and not sampled:
             self._decode_page_looped(active, P, lp_k)
             self._account_progress(active, tot0)
             return
+        flags = sp = None
+        if sampled:
+            with TraceAnnotation("engine.node.sampling_state"):
+                # static sampling plan (backend / penalty skip / sort
+                # tier) decided host-side from the active params — part
+                # of the jit cache key
+                flags = smp.flags_for([c.sampling for c in active],
+                                      T.padded_vocab(self.cfg))
+                sp = self._sp_device()
+                self._flush_pending_sampling()
         # exact step count via pow2 decomposition (40 -> 32+8): each chunk
         # is a cached scan executable (≤ log2(P) distinct sizes), chunks
         # chain on device, blocks concatenate on device -> no masked tail
@@ -591,46 +617,49 @@ class NodeEngine:
         # state (fold_in PRNG position, penalty counts) rides the scan
         # carry and stop-token hits mask slots on device.  Non-fused
         # sampled (baseline): chunk size 1, one transfer per token.
-        rem = np.zeros((self.max_active,), np.int32)
-        for co in active:
-            rem[co.slot] = co.remaining
-        rem_j = self._put(rem)
-        sp = self._sp_device() if sampled else None
-        if sampled:
-            self._flush_pending_sampling()
-        state = self._sample_state
-        blocks = []
-        left = steps
-        while left > 0:
-            chunk = (1 << (left.bit_length() - 1)) if self.fused else 1
-            if self.module_rt is not None:
-                out = self.module_rt.forward_decode_page(
-                    self.tokens, self.cache, self.lengths, rem_j,
-                    self.b_attn, chunk,
-                    sampling=(sp, state) if sampled else None, lp_k=lp_k,
-                    flags=flags)
-            else:
-                mega = self._get_megastep(chunk, sampled, lp_k, flags)
-                args = (self.params, self.cache, self.tokens, self.lengths,
-                        rem_j) + ((sp, state) if sampled else ())
-                out = mega(*args)
+        with TraceAnnotation("engine.node.megastep", steps=steps):
+            rem = np.zeros((self.max_active,), np.int32)
+            for co in active:
+                rem[co.slot] = co.remaining
+            rem_j = self._put(rem)
+            state = self._sample_state
+            blocks = []
+            left = steps
+            while left > 0:
+                chunk = (1 << (left.bit_length() - 1)) if self.fused else 1
+                if self.module_rt is not None:
+                    out = self.module_rt.forward_decode_page(
+                        self.tokens, self.cache, self.lengths, rem_j,
+                        self.b_attn, chunk,
+                        sampling=(sp, state) if sampled else None,
+                        lp_k=lp_k, flags=flags)
+                else:
+                    mega = self._get_megastep(chunk, sampled, lp_k, flags)
+                    args = (self.params, self.cache, self.tokens,
+                            self.lengths, rem_j) + ((sp, state) if sampled
+                                                    else ())
+                    out = mega(*args)
+                if sampled:
+                    (blk, self.tokens, self.lengths, rem_j, self.cache,
+                     state) = out
+                else:
+                    blk, self.tokens, self.lengths, rem_j, self.cache = out
+                blocks.append(blk if self.fused else self._to_host(blk))
+                left -= chunk
             if sampled:
-                blk, self.tokens, self.lengths, rem_j, self.cache, state = \
-                    out
-            else:
-                blk, self.tokens, self.lengths, rem_j, self.cache = out
-            blocks.append(blk if self.fused else self._to_host(blk))
-            left -= chunk
-        if sampled:
-            self._sample_state = state
-        self.decode_steps += steps
+                self._sample_state = state
+            self.decode_steps += steps
+            if self.fused:
+                block = (blocks[0] if len(blocks) == 1
+                         else jnp.concatenate(blocks))
         if self.fused:
-            block = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks)
-            block_np = self._to_host(block)  # the ONE d2h transfer per page
+            with TraceAnnotation("engine.node.block_wait"):
+                block_np = self._to_host(block)  # the ONE d2h per page
         else:
             block_np = np.concatenate(blocks)
-        self._apply_block(active, block_np, steps)
-        self._account_progress(active, tot0)
+        with TraceAnnotation("engine.node.apply_block"):
+            self._apply_block(active, block_np, steps)
+            self._account_progress(active, tot0)
 
     def _account_progress(self, active: Sequence[SequenceCoroutine],
                           tot0: int) -> None:
@@ -700,7 +729,7 @@ class NodeEngine:
                                          lp_k=lp_k)
             return jax.jit(_mega, donate_argnums=(1,))
         return _lru_get(self._megastep_cache, (steps, sampled, lp_k, flags),
-                        _MEGASTEP_JIT_CAP, make)
+                        _MEGASTEP_JIT_CAP, make, self)
 
     def _get_prefill_sampler(self, n: int, flags):
         """Jitted first-token draw (keys = fold_in(base, 0)); without the
@@ -713,7 +742,7 @@ class NodeEngine:
                                   flags)
             return jax.jit(_draw)
         return _lru_get(self._prefill_sample_cache, (n, flags),
-                        _PREFILL_JIT_CAP, make)
+                        _PREFILL_JIT_CAP, make, self)
 
     def _decode_page_looped(self, active: Sequence[SequenceCoroutine],
                             P: int, lp_k=None):
@@ -793,7 +822,7 @@ class NodeEngine:
         syncs reuse a handful of executables."""
         names = tuple(m[0] for m in self._blob_metas)
         return _lru_get(self._gather_cache, (n, W), _GATHER_JIT_CAP,
-                        lambda: jax.jit(partial(_gather_blob, names)))
+                        lambda: jax.jit(partial(_gather_blob, names)), self)
 
     def _gather_dirty(self, active) -> Optional[_InFlightSync]:
         """Issue the batched gather of every dirty slot's [synced, length)
@@ -841,31 +870,32 @@ class NodeEngine:
         device→host copy; the blob rides the ring buffer until
         ``drain_appends`` lands it.  With ``overlap=False`` (or when the
         blob cannot fit the ring even after a forced drain) this degrades
-        to the blocking synchronous path."""
-        ent = self._gather_dirty(active)
-        if ent is None:
-            return
-        if not self.overlap:
-            self._materialize(ent)
-            return
-        if not self.ring.can_fit(ent.nbytes):
-            # backpressure: land everything in flight, then retry the
-            # reservation — the stall the plan optimizer sizes
-            # ring_buffer_bytes against
-            self.sync_stalls += 1
-            self.drain_appends()
-        if self.ring.can_fit(ent.nbytes):
-            try:
-                self.transfer("stage", ent.blob.copy_to_host_async)
-            except TransferDeadLetter:
-                self._abandon_blob(ent)
+        to the blocking synchronous path.  Runs in an
+        ``engine.node.gather`` span."""
+        with TraceAnnotation("engine.node.gather"):
+            ent = self._gather_dirty(active)
+            if ent is None:
                 return
-            self.ring.reserve(ent.name, ent.nbytes)
-            self._inflight.append(ent)
-            self.sync_stages += 1
-            self.staged_bytes += ent.nbytes
-        else:
-            self._materialize(ent)      # blob larger than the whole ring
+            if not self.overlap:
+                self._materialize(ent)
+                return
+            if not self.ring.can_fit(ent.nbytes):
+                # backpressure: land everything in flight, then retry the
+                # reservation — the stall the plan optimizer sizes
+                # ring_buffer_bytes against
+                self.sync_stalls += 1
+                self.drain_appends()
+            if self.ring.can_fit(ent.nbytes):
+                try:
+                    self.transfer("stage", ent.blob.copy_to_host_async)
+                except TransferDeadLetter:
+                    self._abandon_blob(ent)
+                    return
+                self.ring.reserve(ent.name, ent.nbytes)
+                self._inflight.append(ent)
+                self.sync_stages += 1
+            else:
+                self._materialize(ent)  # blob larger than the whole ring
 
     def drain_appends(self, keep_newest: int = 0):
         """Land staged blobs in the host store, oldest first.  The
@@ -877,36 +907,39 @@ class NodeEngine:
             ent = self._inflight.popleft()
             self.ring.release(ent.name)
             self._materialize(ent)
-            self.sync_drains += 1
 
     def _materialize(self, ent: _InFlightSync):
         """Blocking half of the pipeline: wait for the blob's copy (the
         ONE host transfer for the page's KV) and append it page-by-page
-        into the host store."""
-        t0 = time.perf_counter()
-        try:
-            blob = self.transfer("drain", lambda: self._to_host(ent.blob))
-        except TransferDeadLetter:
-            self._abandon_blob(ent)
-            return
-        finally:
-            self.sync_wait_s += time.perf_counter() - t0
-        offs, off = {}, 0
-        for name, trail, f in ent.metas:
-            offs[name] = (off, off + f)
-            off += f
-        L = blob.shape[0]
-        for i, (seq_id, start, n, first) in enumerate(ent.snaps):
-            if not first and not self.host_store.has(seq_id):
-                continue    # dropped (evicted) after issue: do not resurrect
-            slices = {}
-            for name, trail, _ in ent.metas:
-                lo, hi = offs[name]
-                slices[name] = blob[:, i, :n, lo:hi].reshape((L, n) + trail)
-            if self.host_store.has(seq_id):
-                self.host_store.append_tokens(seq_id, slices, start)
-            else:
-                self.host_store.checkpoint(seq_id, slices, start + n)
+        into the host store.  Runs in an ``engine.node.materialize``
+        span."""
+        with TraceAnnotation("engine.node.materialize", blob=ent.name):
+            t0 = time.perf_counter()
+            try:
+                blob = self.transfer("drain",
+                                     lambda: self._to_host(ent.blob))
+            except TransferDeadLetter:
+                self._abandon_blob(ent)
+                return
+            finally:
+                self.sync_wait_s += time.perf_counter() - t0
+            offs, off = {}, 0
+            for name, trail, f in ent.metas:
+                offs[name] = (off, off + f)
+                off += f
+            L = blob.shape[0]
+            for i, (seq_id, start, n, first) in enumerate(ent.snaps):
+                if not first and not self.host_store.has(seq_id):
+                    continue    # evicted since the gather: stays dropped
+                slices = {}
+                for name, trail, _ in ent.metas:
+                    lo, hi = offs[name]
+                    slices[name] = blob[:, i, :n, lo:hi].reshape(
+                        (L, n) + trail)
+                if self.host_store.has(seq_id):
+                    self.host_store.append_tokens(seq_id, slices, start)
+                else:
+                    self.host_store.checkpoint(seq_id, slices, start + n)
 
     def _abandon_blob(self, ent: _InFlightSync):
         """A staged blob was lost to a dead-lettered transfer.  Its
@@ -940,23 +973,25 @@ class NodeEngine:
             self.discard_restore(co.seq_id)     # stale: checkpoint advanced
         if not self.host_store.has(co.seq_id):
             return False
-        t0 = time.perf_counter()
-        slices = self.host_store.restore(co.seq_id, self.max_len)
-        nbytes = sum(int(np.asarray(v).nbytes) for v in slices.values())
-        if not self.restore_ring.can_fit(nbytes):
-            self.restore_stalls += 1
-            return False
-        try:
-            dev = self.transfer("restore", lambda: self._put(slices))
-        except TransferDeadLetter:
-            return False
-        self.restore_ring.reserve(f"restore{co.seq_id}", nbytes)
-        self._restore_staged[co.seq_id] = (
-            dev, self.host_store.seqs[co.seq_id].length,
-            f"restore{co.seq_id}", nbytes, time.perf_counter() - t0)
-        self.restore_stages += 1
-        self.restore_staged_bytes += nbytes
-        return True
+        with TraceAnnotation("engine.node.restore", seq=co.seq_id):
+            t0 = time.perf_counter()
+            slices = self.host_store.restore(co.seq_id, self.max_len)
+            nbytes = sum(int(np.asarray(v).nbytes)
+                         for v in slices.values())
+            if not self.restore_ring.can_fit(nbytes):
+                self.restore_stalls += 1
+                return False
+            try:
+                dev = self.transfer("restore", lambda: self._put(slices))
+            except TransferDeadLetter:
+                return False
+            self.restore_ring.reserve(f"restore{co.seq_id}", nbytes)
+            self._restore_staged[co.seq_id] = (
+                dev, self.host_store.seqs[co.seq_id].length,
+                f"restore{co.seq_id}", nbytes, time.perf_counter() - t0)
+            self.restore_stages += 1
+            self.restore_staged_bytes += nbytes
+            return True
 
     def restore_ready(self, seq_id: int) -> bool:
         """True when the sequence's staged restore has drained: a live
@@ -986,9 +1021,10 @@ class NodeEngine:
                 return dev
         if cur is None:
             return None
-        t0 = time.perf_counter()
-        slices = self.host_store.restore(seq_id, self.max_len)
-        self.restore_wait_s += time.perf_counter() - t0
+        with TraceAnnotation("engine.node.restore", seq=seq_id):
+            t0 = time.perf_counter()
+            slices = self.host_store.restore(seq_id, self.max_len)
+            self.restore_wait_s += time.perf_counter() - t0
         return slices
 
     def discard_restore(self, seq_id: int) -> None:
@@ -1062,56 +1098,93 @@ class NodeEngine:
         lead_rows: Dict[int, object] = {}   # lead seq_id -> (V,) logits row
         fresh_logits = None
         if fresh:
-            maxlen = max(c.prompt_len for c in fresh)
-            S = max(_pow2(maxlen), 8)         # pow2 sequence bucket
-            B = max(_pow2(len(fresh)), 1)     # pow2 batch bucket (padded)
-            toks = np.zeros((B, S), np.int32)  # left-align, pad after
-            last_idx = np.zeros((B,), np.int32)
-            for i, c in enumerate(fresh):
-                toks[i, : c.prompt_len] = c.prompt[:]
-                last_idx[i] = c.prompt_len - 1
-            fn = _lru_get(self._prefill_cache, (B, S), _PREFILL_JIT_CAP,
-                          lambda: jax.jit(partial(_prefill_logits, self.cfg,
-                                                  self.axes)))
-            fresh_logits, cache = fn(self.params,
-                                     *self._put((toks, last_idx)))
-            nf = len(fresh)
-            # batched host-checkpoint gather: flatten every leaf's first-nf
-            # rows into ONE (L, nf, W, F_total) blob and move it with a
-            # single host transfer (the per-sequence/per-leaf slicing this
-            # replaces paid n_seqs * n_leaves small copies per batch)
-            W = maxlen
-            assert len({leaf.dtype for leaf in cache.values()}) == 1, \
-                "batched gather concatenates leaves: mixed dtypes would " \
-                "be silently promoted — add a per-dtype blob before " \
-                "relaxing this"
-            metas, parts = [], []
-            for name, leaf in cache.items():
-                seg = leaf[:, :nf, :W]              # (L, nf, W, *trail)
-                trail = seg.shape[3:]
-                metas.append((name, trail,
-                              int(np.prod(trail)) if trail else 1))
-                parts.append(seg.reshape(seg.shape[0], nf, W, -1))
-            blob = self._to_host(jnp.concatenate(parts, axis=-1))
-            offs, off = {}, 0
-            for name, trail, f in metas:
-                offs[name] = (off, off + f)
-                off += f
-            L = blob.shape[0]
-            for i, lead in enumerate(fresh):
-                pl = lead.prompt_len
-                slices = {}
-                for name, trail, _ in metas:
-                    lo, hi = offs[name]
-                    slices[name] = blob[:, i, :pl, lo:hi].reshape(
-                        (L, pl) + trail)
-                self.host_store.checkpoint(lead.seq_id, slices, pl)
-                lead_rows[lead.seq_id] = fresh_logits[i, 0, :]
-                self.prefill_tokens += pl
+            with TraceAnnotation("engine.node.prefill_forward",
+                                 seqs=[c.seq_id for c in fresh]):
+                fresh_logits = self._prefill_fresh(fresh, lead_rows)
+        if hits:
+            with TraceAnnotation("engine.node.prefix_graft",
+                                 seqs=list(hits)):
+                self._graft_prefix_hits(leads, hits, lead_rows)
 
-        # prefix-hit leads: graft the span's host pages into a dense cache
-        # and teacher-force only the tail (at most one page + the partial
-        # block) through the decode step
+        # publish every lead's prompt pages (dedupes to canonical frozen
+        # spans), then bind fork siblings to the lead's span COW
+        if idx is not None:
+            for group in groups.values():
+                lead = group[0]
+                self.host_store.publish_prefix(lead.seq_id, lead.prompt)
+                for sib in group[1:]:
+                    self.host_store.clone_shared(lead.seq_id, sib.seq_id)
+                    sib.prefix_hit_tokens = sib.prompt_len
+                    self.prefill_tokens_saved += sib.prompt_len
+
+        with TraceAnnotation("engine.node.first_token"):
+            self._first_tokens(cos, fresh, fresh_logits, lead_rows, lead_of)
+        f = 1.0
+        if self.faults is not None:
+            f = max(self.faults.straggler_factor(), 1.0)
+        self.tokens_out += len(cos) / f     # one first token per sequence
+
+    def _prefill_fresh(self, fresh: List[SequenceCoroutine],
+                       lead_rows: Dict[int, object]):
+        """The bucketed prompt forward of the leads with no prefix hit,
+        their prompt KV checkpointed to the host store; fills
+        ``lead_rows`` and returns the batch's last-position logits."""
+        maxlen = max(c.prompt_len for c in fresh)
+        S = max(_pow2(maxlen), 8)         # pow2 sequence bucket
+        B = max(_pow2(len(fresh)), 1)     # pow2 batch bucket (padded)
+        toks = np.zeros((B, S), np.int32)  # left-align, pad after
+        last_idx = np.zeros((B,), np.int32)
+        for i, c in enumerate(fresh):
+            toks[i, : c.prompt_len] = c.prompt[:]
+            last_idx[i] = c.prompt_len - 1
+        fn = _lru_get(self._prefill_cache, (B, S), _PREFILL_JIT_CAP,
+                      lambda: jax.jit(partial(_prefill_logits, self.cfg,
+                                              self.axes)), self)
+        fresh_logits, cache = fn(self.params,
+                                 *self._put((toks, last_idx)))
+        nf = len(fresh)
+        # batched host-checkpoint gather: flatten every leaf's first-nf
+        # rows into ONE (L, nf, W, F_total) blob and move it with a
+        # single host transfer (the per-sequence/per-leaf slicing this
+        # replaces paid n_seqs * n_leaves small copies per batch)
+        W = maxlen
+        assert len({leaf.dtype for leaf in cache.values()}) == 1, \
+            "batched gather concatenates leaves: mixed dtypes would " \
+            "be silently promoted — add a per-dtype blob before " \
+            "relaxing this"
+        metas, parts = [], []
+        for name, leaf in cache.items():
+            seg = leaf[:, :nf, :W]              # (L, nf, W, *trail)
+            trail = seg.shape[3:]
+            metas.append((name, trail,
+                          int(np.prod(trail)) if trail else 1))
+            parts.append(seg.reshape(seg.shape[0], nf, W, -1))
+        blob = self._to_host(jnp.concatenate(parts, axis=-1))
+        offs, off = {}, 0
+        for name, trail, f in metas:
+            offs[name] = (off, off + f)
+            off += f
+        L = blob.shape[0]
+        for i, lead in enumerate(fresh):
+            pl = lead.prompt_len
+            slices = {}
+            for name, trail, _ in metas:
+                lo, hi = offs[name]
+                slices[name] = blob[:, i, :pl, lo:hi].reshape(
+                    (L, pl) + trail)
+            self.host_store.checkpoint(lead.seq_id, slices, pl)
+            lead_rows[lead.seq_id] = fresh_logits[i, 0, :]
+            self.prefill_tokens += pl
+        return fresh_logits
+
+    def _graft_prefix_hits(self, leads: List[SequenceCoroutine],
+                           hits: Dict[int, list],
+                           lead_rows: Dict[int, object]):
+        """Prefix-hit leads: graft the span's host pages into a dense
+        cache and teacher-force only the tail (at most one page + the
+        partial block) through the decode step."""
+        names = list(self.cache.keys())
+        P = self.host_store.page_size
         for lead in leads:
             chain = hits.get(lead.seq_id)
             if chain is None:
@@ -1146,17 +1219,12 @@ class NodeEngine:
             self.prefill_tokens += pl - m
             self.prefill_tokens_saved += m
 
-        # publish every lead's prompt pages (dedupes to canonical frozen
-        # spans), then bind fork siblings to the lead's span COW
-        if idx is not None:
-            for group in groups.values():
-                lead = group[0]
-                self.host_store.publish_prefix(lead.seq_id, lead.prompt)
-                for sib in group[1:]:
-                    self.host_store.clone_shared(lead.seq_id, sib.seq_id)
-                    sib.prefix_hit_tokens = sib.prompt_len
-                    self.prefill_tokens_saved += sib.prompt_len
-
+    def _first_tokens(self, cos: Sequence[SequenceCoroutine],
+                      fresh: List[SequenceCoroutine], fresh_logits,
+                      lead_rows: Dict[int, object],
+                      lead_of: Dict[int, int]):
+        """Draw each sequence's first token from its lead's logits row
+        (with its log-probs when asked) and leave it INACTIVE."""
         n = len(cos)
         if fresh_logits is not None and len(fresh) == n:
             logits2d = fresh_logits[:n, 0, :]   # no dedupe/hit: batch rows
@@ -1204,10 +1272,6 @@ class NodeEngine:
             co.phase = Phase.DECODING
             co.status = Status.INACTIVE
             self.synced_len[co.seq_id] = pl
-        f = 1.0
-        if self.faults is not None:
-            f = max(self.faults.straggler_factor(), 1.0)
-        self.tokens_out += len(cos) / f     # one first token per sequence
 
 
 # NodeEngine declares conformance to the formal backend contract; the

@@ -123,6 +123,7 @@ from repro.core.events import (Event, EventKind, EventQueue, HealthEvent,
 from repro.runtime.failure import HealthMonitor, ProgressTracker
 from repro.runtime.faults import FaultPlan, TransferDeadLetter
 from repro.sampling.params import SamplingParams, derive_fork_seed
+from repro.sampling.sample import SAMPLE_TIERS
 
 logger = logging.getLogger(__name__)
 
@@ -1385,6 +1386,10 @@ class CoroutineScheduler:
         for e in self._all_engines:
             for k in engine:
                 engine[k] += getattr(e, k, 0)
+        # sampled decode pages by the path they sampled on
+        engine["sample_tier_pages"] = {
+            t: sum(getattr(e, "sample_tier_pages", {}).get(t, 0)
+                   for e in self._all_engines) for t in SAMPLE_TIERS}
         xfer = {"retries": 0, "timeouts": 0, "dead_letters": 0}
         for e in self._all_engines:
             for k in xfer:

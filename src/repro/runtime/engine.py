@@ -316,6 +316,8 @@ class NodeEngine:
         self._install_cache: "OrderedDict[int, object]" = OrderedDict()
         self.install_s = 0.0            # host wall time flushing installs
         self.slots_installed = 0        # slots written (not pow2 padding)
+        # sampled decode pages by the path their plan samples on
+        self.sample_tier_pages = dict.fromkeys(smp.SAMPLE_TIERS, 0)
 
         # ---- staged h2d restores (stage_restore / take_restore) -----------
         # the host→device mirror of the d2h sync pipeline: a suspended
@@ -606,6 +608,7 @@ class NodeEngine:
                 # of the jit cache key
                 flags = smp.flags_for([c.sampling for c in active],
                                       T.padded_vocab(self.cfg))
+                self.sample_tier_pages[flags.tier] += 1
                 sp = self._sp_device()
                 self._flush_pending_sampling()
         # exact step count via pow2 decomposition (40 -> 32+8): each chunk
@@ -617,7 +620,8 @@ class NodeEngine:
         # state (fold_in PRNG position, penalty counts) rides the scan
         # carry and stop-token hits mask slots on device.  Non-fused
         # sampled (baseline): chunk size 1, one transfer per token.
-        with TraceAnnotation("engine.node.megastep", steps=steps):
+        with TraceAnnotation("engine.node.megastep", steps=steps,
+                             tier=flags.tier if sampled else "greedy"):
             rem = np.zeros((self.max_active,), np.int32)
             for co in active:
                 rem[co.slot] = co.remaining

@@ -14,11 +14,12 @@ The subsystem has three layers:
   reproduces greedy argmax bit-for-bit.
 * ``sample``     — the batched ``sample`` entry point and the scan-step
   ``sample_step``, dispatching on a static :class:`SampleFlags` plan
-  (``flags_for``): the Pallas fused-sampling kernel on TPU
-  (``repro.kernels.fused_sampling``) or one of three shared-sort XLA
-  tiers, with penalty/stop/greedy-select ops statically dropped when no
-  active slot needs them; plus the deterministic PRNG-state helpers
-  threaded as scan carry through the fused decode megastep.
+  (``flags_for``): one of three XLA tiers (sortless, top-k lanes, full
+  sort), or on a TPU for the full sort the Pallas fused-sampling kernel
+  (``repro.kernels.fused_sampling``), with penalty/stop/greedy-select
+  ops statically dropped when no active slot needs them; plus the
+  deterministic PRNG-state helpers threaded as scan carry through the
+  fused decode megastep.
 
 Reproducibility contract: the key used for a sequence's t-th sampled
 token is ``fold_in(PRNGKey(seed), t)`` — a pure function of the
@@ -33,17 +34,18 @@ from repro.sampling.processors import (apply_min_p, apply_penalties,
                                        apply_temperature, apply_top_k,
                                        apply_top_p, joint_filter,
                                        joint_threshold, process_logits)
-from repro.sampling.sample import (DEFAULT_FLAGS, SampleFlags, base_keys,
-                                   base_keys_host, default_backend,
-                                   flags_for, init_state, sample,
-                                   sample_one, sample_step, step_keys,
-                                   stop_hit, token_gumbel)
+from repro.sampling.sample import (DEFAULT_FLAGS, KC_MAX, SAMPLE_TIERS,
+                                   SampleFlags, base_keys, base_keys_host,
+                                   default_backend, flags_for, init_state,
+                                   sample, sample_one, sample_step,
+                                   step_keys, stop_hit, token_gumbel)
 
 __all__ = [
     "MAX_STOP_TOKENS", "SamplingParams", "derive_fork_seed", "pack_params",
     "apply_penalties", "apply_temperature", "apply_top_k", "apply_top_p",
     "apply_min_p", "joint_threshold", "joint_filter", "process_logits",
-    "DEFAULT_FLAGS", "SampleFlags", "base_keys", "base_keys_host",
-    "default_backend", "flags_for", "init_state", "sample", "sample_one",
-    "sample_step", "step_keys", "stop_hit", "token_gumbel",
+    "DEFAULT_FLAGS", "KC_MAX", "SAMPLE_TIERS", "SampleFlags", "base_keys",
+    "base_keys_host", "default_backend", "flags_for", "init_state",
+    "sample", "sample_one", "sample_step", "step_keys", "stop_hit",
+    "token_gumbel",
 ]

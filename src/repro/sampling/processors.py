@@ -32,9 +32,11 @@ Three statically-selected tiers share the same threshold semantics
 * ``kc == -1`` — no sort at all (only temperature / min-p / penalties
   active anywhere: tau_m needs just the row max).
 
-On TPU the XLA tiers are the *fallback*; the Pallas kernel in
-``repro.kernels.fused_sampling`` derives the same joint threshold with a
-tiled histogram refinement and no materialised sorted copies at all.
+On TPU the Pallas kernel in ``repro.kernels.fused_sampling`` runs the
+full-sort tier (and lane caps above ``sample.KC_MAX``): it derives the
+same joint threshold with a tiled histogram refinement and no
+materialised sorted copies at all.  The sortless and lane tiers are
+faster in XLA there too (``sample.flags_for``).
 
 Every processor remains an EXACT identity at its parameter's disabled
 value: dividing by a 1.0 penalty and scaling by a 1.0 temperature are

@@ -27,14 +27,16 @@ are wasteful to decide on device every step, so the engine derives them
 ON THE HOST from the active batch's SamplingParams and bakes them into
 the megastep executable (they are part of the jit cache key):
 
-* ``backend`` — ``"pallas"`` (TPU) routes the filter + draw through the
-  fused single-pass kernel (``repro.kernels.fused_sampling``), compiled;
-  ``"xla"`` is the shared-sort path for every other platform (CPU CI).
-  The platform alone decides (``default_backend``).
-* ``pen`` — False drops the penalty ops AND the per-step (B, V) count
-  updates from the scan when no active slot enables a penalty.
 * ``kc`` — the sort tier of ``processors.joint_threshold``: 0 full
   sort, > 0 partial ``lax.top_k`` sort, -1 sortless.
+* ``backend`` — ``"pallas"`` routes the filter + draw through the fused
+  single-pass kernel (``repro.kernels.fused_sampling``), compiled;
+  ``"xla"`` runs the ``kc`` tier in plain XLA.  The platform and the
+  tier decide together: the kernel only on a TPU (``default_backend``)
+  and only for the full-sort tier or a lane cap above ``KC_MAX``, where
+  it beats XLA's sort; the sortless and lane tiers are faster in XLA.
+* ``pen`` — False drops the penalty ops AND the per-step (B, V) count
+  updates from the scan when no active slot enables a penalty.
 
 Every tier (and the kernel) consumes the same TOKEN-indexed noise from
 the same fold_in key and computes the same kept set, so the realized
@@ -68,21 +70,43 @@ import numpy as np
 from repro.sampling.processors import apply_penalties, apply_temperature
 
 
+# The largest lane cap at which the lane tier (one ``lax.top_k`` plus
+# (B, kc) lane math) beats the fused-sampling kernel on a TPU v5e, at
+# (32, 151936) f32 with penalties and top-5 log-prob lanes
+# (benchmarks/sampling_tiers.py; PERF.md section 5).
+KC_MAX = 4096
+
+SAMPLE_TIERS = ("kernel", "lanes", "sortless", "sort")
+
+
 @dataclasses.dataclass(frozen=True)
 class SampleFlags:
-    """Static (host-decided, jit-keyed) execution plan for one megastep."""
+    """Static (host-decided, jit-keyed) execution plan for one megastep:
+    the ``kc`` tier, and the kernel or XLA to run it (``flags_for``)."""
     backend: str = "xla"     # "xla" | "pallas"
     pen: bool = True         # any penalty enabled in the active batch
     kc: int = 0              # sort tier: 0 full, >0 top-kc, -1 sortless
     mixed: bool = True       # any greedy (temperature <= 0) row present
     stops: bool = True       # any stop-token set non-empty
 
+    @property
+    def tier(self) -> str:
+        """The path a page with this plan samples on, one of
+        ``SAMPLE_TIERS``: the kernel, or XLA's lane, sortless or
+        full-sort tier."""
+        if self.backend == "pallas":
+            return "kernel"
+        return "lanes" if self.kc > 0 else "sortless" if self.kc < 0 \
+            else "sort"
+
 
 DEFAULT_FLAGS = SampleFlags()
 
 
 def default_backend() -> str:
-    """The compiled kernel on TPUs, shared-sort XLA everywhere else."""
+    """Where the fused-sampling kernel can run: ``"pallas"`` on TPUs,
+    ``"xla"`` everywhere else.  ``flags_for`` uses the kernel only for
+    the tiers it wins on this platform."""
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
@@ -98,7 +122,11 @@ def flags_for(sps, vocab: int) -> SampleFlags:
     requires EVERY drawing row (temperature > 0, not greedy-default) to
     have top-k active — a filterless or top-p-only row samples from a
     set no static cap bounds, so any such row forces the full-sort tier;
-    greedy rows are fine either way (lane 0 IS the argmax)."""
+    greedy rows are fine either way (lane 0 IS the argmax).
+
+    The kernel runs a tier only where it beats XLA: on a TPU, for the
+    full-sort tier (kc == 0) or a lane cap above ``KC_MAX``.  The
+    sortless and the other lane tiers take XLA's code."""
     act = [s for s in sps if not s.is_greedy_default]
     pen = any(s.repetition_penalty != 1.0 or s.presence_penalty != 0.0
               or s.frequency_penalty != 0.0 for s in act)
@@ -112,8 +140,9 @@ def flags_for(sps, vocab: int) -> SampleFlags:
         kc = 0
     else:
         kc = -1
-    return SampleFlags(backend=default_backend(), pen=pen, kc=kc,
-                       mixed=any(s.temperature <= 0.0 for s in sps),
+    kernel = default_backend() == "pallas" and (kc == 0 or kc > KC_MAX)
+    return SampleFlags(backend="pallas" if kernel else "xla", pen=pen,
+                       kc=kc, mixed=any(s.temperature <= 0.0 for s in sps),
                        stops=any(s.stop for s in sps))
 
 
@@ -293,11 +322,11 @@ def _sample_topk_lanes(logits, counts_full, counts_gen, sp, keys,
     argmax all run on (B, kc) instead of (B, V) — the per-step sort work
     drops from O(V log V) to O(V log kc) (the benchmark sweep's
     large-vocab scaling) and the greedy token is lane 0 for free.
-    Gumbel noise stays TOKEN-indexed: the full fold_in(seed, t) noise
-    row is drawn and gathered at the lane token ids, so every tier, the
-    Pallas kernel and the looped baseline realize the identical stream
-    for the same (seed, t, logits) regardless of which tier the batch
-    composition selects."""
+    Gumbel noise stays TOKEN-indexed: it is hashed at the lane token
+    ids alone, and equals the full fold_in(seed, t) noise row there, so
+    every tier, the Pallas kernel and the looped baseline realize the
+    identical stream for the same (seed, t, logits) regardless of which
+    tier the batch composition selects."""
     from repro.sampling.processors import _NEG_INF, tau_from_sorted_rows
 
     x = logits.astype(jnp.float32)
@@ -306,7 +335,11 @@ def _sample_topk_lanes(logits, counts_full, counts_gen, sp, keys,
                                       sp["repetition_penalty"],
                                       sp["presence_penalty"],
                                       sp["frequency_penalty"])
-    sl, si = jax.lax.top_k(x, flags.kc)              # the ONE (B, V) pass
+    # the ONE (B, V) pass.  The barrier keeps it XLA's TopK on a TPU:
+    # without it the lane-0 slices below fold into the top-k and the
+    # compiler sorts the whole row instead (6-7 ms at 32 x 151936 on a
+    # v5e, against 0.5 ms; PERF.md section 5)
+    sl, si = jax.lax.optimization_barrier(jax.lax.top_k(x, flags.kc))
     scale = jnp.where(sp["temperature"] > 0.0, sp["temperature"], 1.0)
     sl = sl / scale[:, None]
     tau = tau_from_sorted_rows(sl, sp["top_k"], sp["top_p"], sp["min_p"])

@@ -2,6 +2,8 @@
 the shared-sort XLA fallback vs the sequential per-filter pipeline, tier
 agreement, chi-square distribution checks against the PR 2 three-sort
 semantics, and the pre-filter logprob-lane contract."""
+import importlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -337,3 +339,73 @@ def test_flags_for_tiers():
     # a top-k larger than the vocab degenerates to the full-sort tier
     assert S.flags_for([SamplingParams(temperature=1.0, top_k=4000,
                                        seed=0)], 4096).kc == 0
+
+
+# (plan, its kc, its tier on a TPU, its tier elsewhere): rollout's
+# temperature-1 rows, longtail's Instruct card beside a greedy row, a
+# top-p-only row, and lane caps at and above KC_MAX
+TIER_PLANS = {
+    "rollout": ([SamplingParams(temperature=1.0, seed=0), SamplingParams()],
+                -1, "sortless", "sortless"),
+    "longtail": ([SamplingParams(temperature=0.7, top_k=20, top_p=0.8,
+                                 repetition_penalty=1.1, seed=0),
+                  SamplingParams()], 32, "lanes", "lanes"),
+    "top_p": ([SamplingParams(temperature=0.8, top_p=0.9, seed=0)],
+              0, "kernel", "sort"),
+    "kc_max": ([SamplingParams(temperature=1.0, top_k=S.KC_MAX, seed=0)],
+               S.KC_MAX, "lanes", "lanes"),
+    "above_kc_max": ([SamplingParams(temperature=1.0, top_k=S.KC_MAX + 1,
+                                     seed=0)],
+                     2 * S.KC_MAX, "kernel", "lanes"),
+}
+
+
+@pytest.mark.parametrize("plan", sorted(TIER_PLANS))
+def test_flags_for_runs_the_kernel_only_where_it_wins(plan, monkeypatch):
+    """On a TPU the kernel takes the full-sort tier and lane caps above
+    KC_MAX; the sortless and smaller lane tiers stay in XLA.  Off the
+    TPU every tier is XLA's."""
+    sps, kc, on_tpu, off_tpu = TIER_PLANS[plan]
+    V = 151936
+    f = S.flags_for(sps, V)
+    assert (f.kc, f.backend, f.tier) == (kc, "xla", off_tpu)
+    monkeypatch.setattr(importlib.import_module("repro.sampling.sample"),
+                        "default_backend", lambda: "pallas")
+    f = S.flags_for(sps, V)
+    assert (f.kc, f.tier) == (kc, on_tpu)
+    assert f.backend == ("pallas" if on_tpu == "kernel" else "xla")
+
+
+@pytest.mark.parametrize("plan", ["rollout", "longtail", "top_p"])
+def test_engine_counts_sampled_pages_by_tier(plan):
+    """``sample_tier_pages`` counts one page per sampled megastep, under
+    its plan's tier; pages with only greedy rows left count nowhere, and
+    the scheduler's report sums the counts."""
+    from repro.configs import reduced_config
+    from repro.core.scheduler import CoroutineScheduler, SchedulerConfig
+    from repro.runtime.engine import NodeEngine
+
+    sps, _, _, tier = TIER_PLANS[plan]
+    eng = NodeEngine(reduced_config("llama3_2_1b"), max_active=3,
+                     max_len=64, page_size=8, seed=0)
+    ran = []
+    decode_page = eng.decode_page
+
+    def spy(active, P):
+        steps = eng.decode_steps
+        decode_page(active, P)
+        if (eng.decode_steps > steps
+                and any(not c.sampling.is_greedy_default for c in active)):
+            ran.append(S.flags_for([c.sampling for c in active],
+                                   eng.cfg.vocab_size).tier)
+    eng.decode_page = spy
+    sched = CoroutineScheduler([eng], SchedulerConfig(page_size=8))
+    rows = [sps[0], SamplingParams(), sps[0]]
+    sched.submit([[5, 6, 7, 8], [9, 10, 11], [12, 13]], [20, 36, 12],
+                 sampling=rows)
+    report = sched.run(max_ticks=2000)
+    assert report["status"] == "completed"
+    assert ran and set(ran) == {tier}
+    want = {t: ran.count(t) for t in S.SAMPLE_TIERS}
+    assert eng.sample_tier_pages == want
+    assert report["engine"]["sample_tier_pages"] == want

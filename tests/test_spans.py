@@ -17,7 +17,7 @@ from repro.configs import reduced_config
 from repro.core.events import PrimitiveEvent
 from repro.core.scheduler import CoroutineScheduler, SchedulerConfig
 from repro.runtime.engine import NodeEngine
-from repro.sampling import SamplingParams
+from repro.sampling import SAMPLE_TIERS, SamplingParams
 
 SPANS = (
     "engine.sched.round", "engine.sched.refill", "engine.sched.module_ready",
@@ -105,14 +105,23 @@ def test_spans_carry_their_keys(traced):
     assert builds and all("key" in s[3] for s in builds)
     rounds = [s for s in spans if s[2] == "engine.sched.round"]
     assert all("tick" in s[3] for s in rounds)
+    pages = [s for s in spans if s[2] == "engine.node.megastep"]
+    assert pages and all("steps" in s[3] for s in pages)
+    assert {s[3].get("tier") for s in pages} <= {"greedy", *SAMPLE_TIERS}
 
 
 def test_report_sums_engine_counters(traced):
     sched, engines, spans, report = traced
     eng = report["engine"]
-    assert set(eng) == set(COUNTERS)
+    assert set(eng) == set(COUNTERS) | {"sample_tier_pages"}
     for k in COUNTERS:
         assert eng[k] == pytest.approx(sum(getattr(e, k) for e in engines))
+    assert eng["sample_tier_pages"] == {
+        t: sum(e.sample_tier_pages[t] for e in engines)
+        for t in SAMPLE_TIERS}
+    sampled = [s for s in spans if s[2] == "engine.node.megastep"
+               and s[3]["tier"] != "greedy"]
+    assert sampled and sum(eng["sample_tier_pages"].values()) == len(sampled)
     builds = [s for s in spans if s[2] == "engine.node.compile"]
     assert eng["jit_builds"] == len(builds)
     assert eng["install_s"] > 0 and eng["jit_build_s"] > 0

@@ -12,6 +12,7 @@ the module is imported: only one process may hold the TPU library, and
 every test worker imports this file.
 """
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -78,11 +79,27 @@ def test_fused_sample_compiles_for_v5e(one_chip, B, lp_k, park):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("sampled", [False, True])
-def test_decode_page_compiles_for_v5e(one_chip, sampled):
+# The sampled plans of the benchmark's mixes, and one the kernel serves:
+# rollout's temperature-1 rows (sortless, kc -1), longtail's Instruct
+# card (top-k 20, so the lane tier at kc 32) and a top-p-only row (the
+# full-sort tier, kc 0).
+PLANS = {
+    "rollout": [smp.SamplingParams(temperature=1.0, seed=1),
+                smp.SamplingParams()],
+    "longtail": [smp.SamplingParams(temperature=0.7, top_k=20, top_p=0.8,
+                                    repetition_penalty=1.1, seed=1),
+                 smp.SamplingParams()],
+    "top_p": [smp.SamplingParams(temperature=0.8, top_p=0.9, seed=1)],
+}
+PLAN_KC = {"rollout": -1, "longtail": 32, "top_p": 0}
+
+
+@pytest.mark.parametrize("sampled", [False, "rollout", "longtail", "top_p"])
+def test_decode_page_compiles_for_v5e(one_chip, sampled, monkeypatch):
     """The fused decode megastep at qwen2_0_5b widths (2 layers): greedy,
-    and sampled with logprob lanes, whose filter + draw is the compiled
-    kernel."""
+    and sampled with logprob lanes under the plan ``flags_for`` makes on
+    a TPU.  The filter + draw is the compiled kernel for the full-sort
+    tier alone; the sortless and lane tiers are XLA."""
     cfg = dataclasses.replace(get_config("qwen2_0_5b"), num_layers=2)
     axes = MeshAxes(batch=("data",), model="model")
     B, S = 8, 256
@@ -93,15 +110,18 @@ def test_decode_page_compiles_for_v5e(one_chip, sampled):
     vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
     args = (params, cache, vec, vec, vec)
     if sampled:
-        sp = {k: v for k, v in smp.pack_params(
-            [smp.SamplingParams()] * B, list(range(B))).items()
-            if k != "seed"}
+        rows = (PLANS[sampled] * B)[:B]
+        sp = {k: v for k, v in smp.pack_params(rows, list(range(B))).items()
+              if k != "seed"}
         state = {"base_key": np.zeros((B, 2), np.uint32),
                  "gen_count": np.zeros((B,), np.int32),
                  "counts": np.zeros((B, V), np.int32),
                  "prompt_counts": np.zeros((B, V), np.int32)}
-        flags = smp.SampleFlags(backend="pallas", pen=True, kc=32,
-                                mixed=True, stops=False)
+        # the plan as on a TPU: this process's JAX backend is the CPU
+        monkeypatch.setattr(importlib.import_module("repro.sampling.sample"),
+                            "default_backend", lambda: "pallas")
+        flags = smp.flags_for(rows, V)
+        assert flags.kc == PLAN_KC[sampled]
         args += (_sds(sp, one_chip), _sds(state, one_chip))
 
         def step(p, c, t, l, r, s, st):
@@ -110,5 +130,7 @@ def test_decode_page_compiles_for_v5e(one_chip, sampled):
     else:
         def step(p, c, t, l, r):
             return T.decode_page(cfg, axes, p, c, t, l, r, 4)
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == sampled
+    text = jax.jit(step, donate_argnums=(1,)).lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in text) == (sampled == "top_p")
+    # the lane tier's top-k stays XLA's TopK, never a sort of the row
+    assert " sort(" not in text

@@ -11,12 +11,20 @@ Nothing here branches on a cell, configuration or metric name.
 """
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import jax
 
 import workload as wl
+
+# engine attributes every run sums into its counters; a model family adds
+# its own in ``COUNTERS``
+BASE_COUNTERS = ("decode_steps", "prefill_tokens", "prefill_tokens_saved",
+                 "sync_wait_s", "d2h_transfers", "install_s",
+                 "slots_installed", "jit_builds", "jit_build_s",
+                 "sample_tier_pages")
 
 
 @dataclass
@@ -32,6 +40,8 @@ class Tally:
     tokens: int = 0             # output tokens emitted
     decode_tokens: int = 0      # of which produced by decode steps
     context_sum: int = 0        # sum of attended positions over those
+    # decode tokens by attended positions: sums to ``context_sum``
+    context_hist: Counter = field(default_factory=Counter)
     prefill_lens: List[int] = field(default_factory=list)  # prompts forwarded
     jobs: int = 0
     attempted: int = 0
@@ -51,6 +61,7 @@ class Tally:
             else:
                 self.decode_tokens += 1
                 self.context_sum += p + j
+                self.context_hist[p + j] += 1
 
     def finish(self, req: wl.Request, tokens, logprobs):
         if len(tokens) != req.max_tokens:
@@ -99,6 +110,7 @@ class System:
             e.params = model.program_weights(cfg_file, seed, e.device)
         jax.block_until_ready([e.params for e in self.engines])
         self.slots = sum(e.max_active for e in self.engines)
+        self.counter_names = counter_names(model)
 
     def trace_spans(self):
         """Host spans around the engine's calls, for traced runs only."""
@@ -111,10 +123,7 @@ class System:
                     setattr(e, n, _wrapped(fn, f"engine.{n.strip('_')}"))
 
     def counters(self) -> Dict[str, float]:
-        keys = ("decode_steps", "prefill_tokens", "prefill_tokens_saved",
-                "sync_wait_s", "d2h_transfers")
-        return {k: float(sum(getattr(e, k, 0) for e in self.engines))
-                for k in keys}
+        return sum_counters(self.engines, self.counter_names)
 
     def close(self):
         """Drop every reference to the program's device arrays."""
@@ -124,6 +133,26 @@ class System:
                 if hasattr(e, name):
                     setattr(e, name, None)
         self.engines, self.master = [], None
+
+
+def counter_names(model) -> tuple:
+    """The base counters and the model family's ``COUNTERS``."""
+    return BASE_COUNTERS + tuple(getattr(model, "COUNTERS", ()))
+
+
+def sum_counters(engines, names: Sequence[str]) -> Dict[str, float]:
+    """Each named engine attribute summed over ``engines`` (0 where an
+    engine lacks it); a dict-valued one is summed per key, as
+    ``name.key``."""
+    out: Dict[str, float] = {}
+    for k in names:
+        for e in engines:
+            v = getattr(e, k, 0)
+            parts = ({f"{k}.{sub}": x for sub, x in v.items()}
+                     if isinstance(v, dict) else {k: v})
+            for name, x in parts.items():
+                out[name] = out.get(name, 0.0) + float(x)
+    return out
 
 
 def _wrapped(fn, name):

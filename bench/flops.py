@@ -1,19 +1,28 @@
 """Operations and bytes that each algorithm on the serving path needs,
-computed from the configuration's shapes alone.
+computed from the configuration's shapes alone: the parts that model
+families (``bench/models/<family>.py``) build their ``work`` count from.
 
 These are the least work of the algorithm, not of any implementation:
 a step that reads more (a dense cache at its full length, an f32
 upcast, a second copy of a tied head) is slower than this, and its
-roofline share says by how much.  Shapes come from the configuration
-file's published keys (``hidden_size`` and so on); weights are served
-in bf16 (2 bytes an element) and logits enter sampling as f32.
+roofline share says by how much.  ``Dims``, ``decode`` and ``prefill``
+count the dense block with grouped-query attention, from the
+configuration file's published keys (``hidden_size`` and so on);
+weights are served in bf16 (2 bytes an element).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple, Tuple
 
 WEIGHT_BYTES = 2        # bf16, the precision the configurations are served in
-LOGIT_BYTES = 4         # f32 logits, as sampling reads them
+
+
+class Count(NamedTuple):
+    """A family's least work over one traced stretch, as its ``work``
+    returns it: (FLOPs, bytes) of the decode steps and of the prefills."""
+    decode: Tuple[int, int]
+    prefill: Tuple[int, int]
 
 
 @dataclass(frozen=True)
@@ -115,14 +124,6 @@ def prefill(d: Dims, prompt_lens, calls: int):
              + d.attn_flops_per_pair * pairs)
     nbytes = calls * d.weight_bytes + d.kv_bytes_per_position * positions
     return flops, nbytes
-
-
-def sampling(d: Dims, rows: int):
-    """Bytes one sampling call over ``rows`` rows must read: each row's
-    f32 logits over the vocabulary, once.  (Penalties, temperature and
-    the top-k/top-p cut are a few operations per element on data already
-    read, so the call is bound by these bytes.)"""
-    return rows * d.vocab * LOGIT_BYTES
 
 
 def least_seconds(flops: float, nbytes: float, peak_flops: float,

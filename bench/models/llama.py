@@ -1,5 +1,6 @@
 """The Llama block family (Qwen2, SmolLM): seeded weights, the binding
-to the program's parameter layout, and the plain float32 reference.
+to the program's parameter layout, the plain float32 reference, and the
+least work of its serving steps (``work``).
 
 The reference imports nothing of the program.  It follows the published
 architecture: token embedding; per layer a pre-norm RMSNorm, q/k/v
@@ -24,6 +25,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import flops
 
 HEAD_CHUNK = 256        # positions per block of the head's logits
 
@@ -118,6 +121,24 @@ def program_weights(c: dict, seed: int, device):
     fn = jax.jit(lambda k: to_program(c, plain_weights(c, k)),
                  out_shardings=sharding)
     return fn(jax.device_put(key_from_seed(seed), sharding))
+
+
+# ------------------------------------------------------------- least work
+def work(c: dict):
+    """The least work over a traced stretch, by ``flops.py``'s dense
+    block with grouped-query attention at the file's shapes:
+    ``count(stretch)`` gives the ``flops.Count`` of the stretch's decode
+    steps (each token's K/V at its own attended length) and of its
+    prefills.  The stretch does not count batched prefill calls, so the
+    prefills' bytes leave out their weight reads; no reader uses them."""
+    d = flops.dims(c)
+
+    def count(st) -> flops.Count:
+        return flops.Count(
+            decode=flops.decode(d, int(st.counters["decode_steps"]),
+                                st.decode_tokens, st.context_sum),
+            prefill=flops.prefill(d, st.prefill_lens, 0))
+    return count
 
 
 # -------------------------------------------------------------- reference

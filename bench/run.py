@@ -21,6 +21,30 @@ reports the per-layer metrics instead of the end-to-end ones.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 2
 and prints no result.
+
+A model family, ``bench/models/<family>.py`` as the configuration file's
+``model`` key names it, provides:
+
+- ``program_config(cfg, base)``: the program's ``ModelConfig`` (``base``,
+  from the program's registry) with every size taken from the
+  configuration file ``cfg``;
+- ``program_weights(cfg, seed, device)``: the program's weights, made on
+  ``device`` from the seed;
+- ``Reference(cfg, seed, device, max_len, control=False)``: the plain
+  float32 reference, which draws its own weights from the seed and whose
+  ``stats`` ``check.py`` compares with; ``control=True`` gives the
+  lower-precision control;
+- ``work(cfg)``: ``count(stretch)``, the least work of a traced stretch
+  as a ``flops.Count`` (FLOPs and bytes of its decode steps and of its
+  prefills), which readers call as ``ctx.work``.  The stretch holds
+  ``decode_tokens``, ``context_sum``, ``context_hist`` (decode tokens by
+  attended positions), ``prefill_lens`` and ``counters`` (deltas);
+- optionally ``COUNTERS``: names of engine attributes summed into the
+  run's counters beside ``drive.BASE_COUNTERS``, a dict-valued one per
+  key as ``name.key``.
+
+A new architecture's cell therefore needs new files only: its family,
+configuration, mix and limits.
 """
 import time
 
@@ -33,6 +57,7 @@ import json                                                     # noqa: E402
 import os                                                       # noqa: E402
 import shutil                                                   # noqa: E402
 import sys                                                      # noqa: E402
+from collections import Counter                                 # noqa: E402
 from dataclasses import dataclass                               # noqa: E402
 from types import SimpleNamespace                               # noqa: E402
 from typing import Optional                                     # noqa: E402
@@ -47,7 +72,6 @@ import numpy as np                                              # noqa: E402
 
 import check                                                    # noqa: E402
 import drive                                                    # noqa: E402
-import flops                                                    # noqa: E402
 import trace_reduce                                             # noqa: E402
 import workload as wl                                           # noqa: E402
 from compile_clock import CompileClock                          # noqa: E402
@@ -141,6 +165,7 @@ class Tracer:
         t = self.tally
         self.marks.append(dict(
             decode_tokens=t.decode_tokens, context_sum=t.context_sum,
+            context_hist=Counter(t.context_hist),
             prefills=len(t.prefill_lens), counters=self.system.counters()))
 
     def start(self):
@@ -174,9 +199,14 @@ class Tracer:
         return SimpleNamespace(
             decode_tokens=b["decode_tokens"] - a["decode_tokens"],
             context_sum=b["context_sum"] - a["context_sum"],
+            context_hist=b["context_hist"] - a["context_hist"],
             prefill_lens=self.tally.prefill_lens[a["prefills"]:b["prefills"]],
-            counters={k: b["counters"][k] - a["counters"][k]
-                      for k in a["counters"]})
+            counters=_delta(a["counters"], b["counters"]))
+
+
+def _delta(before: dict, after: dict) -> dict:
+    """Counter deltas; a key first seen in ``after`` counts from 0."""
+    return {k: v - before.get(k, 0.0) for k, v in after.items()}
 
 
 def another_job(elapsed: float, jobs: int, seconds: float) -> bool:
@@ -251,7 +281,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
 
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
-    counters = {k: after_c[k] - before_c[k] for k in after_c}
+    counters = _delta(before_c, after_c)
     slots = system.slots
     system.close()
     del system
@@ -274,18 +304,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     if trace:
         summary = trace_reduce.reduce(trace_reduce.find_xplane(TRACE_DIR))
         stretch = tracer.stretch()
-    ctx = SimpleNamespace(
-        config=cell.config, mix=cell.mix, tally=tally, counters=counters,
-        window_s=window_s, setup_s=setup_s, chips=len(devices), slots=slots,
-        dims=flops.dims(cell.config), peaks=peaks,
+    ctx = context(
+        cell, tally=tally, counters=counters, window_s=window_s,
+        setup_s=setup_s, chips=len(devices), slots=slots, peaks=peaks,
         memory_peak_bytes=peak, trace=summary, stretch=stretch)
-    metrics = {}
-    for m in (cell.per_layer if trace else cell.end_to_end):
-        reader = _load(os.path.join(BENCH, "metrics", f"{m['name']}.py"),
-                       f"bench_metric_{m['name']}")
-        v = reader.read(ctx)
-        if v is not None:
-            metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+    metrics = read_metrics(cell.per_layer if trace else cell.end_to_end, ctx)
 
     d0 = devices[0]
     device = {"platform": d0.platform, "kind": d0.device_kind,
@@ -299,6 +322,27 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     result["check"] = {ln["name"]: {"value": ln["value"],
                                     "limit": ln["limit"]} for ln in lines}
     return result
+
+
+def context(cell: Cell, **state) -> SimpleNamespace:
+    """What the metrics' readers read: the run's ``state``, the cell's
+    configuration and mix, and its model family's least-work count as
+    ``work``."""
+    return SimpleNamespace(config=cell.config, mix=cell.mix,
+                           work=cell.model.work(cell.config), **state)
+
+
+def read_metrics(metrics: list, ctx) -> dict:
+    """Each metric's reading by its reader, ``bench/metrics/<name>.py``;
+    a reader that finds nothing to read leaves its metric out."""
+    out = {}
+    for m in metrics:
+        reader = _load(os.path.join(BENCH, "metrics", f"{m['name']}.py"),
+                       f"bench_metric_{m['name']}")
+        v = reader.read(ctx)
+        if v is not None:
+            out[m["name"]] = {"value": float(v), "unit": m["unit"]}
+    return out
 
 
 def main(argv=None) -> int:

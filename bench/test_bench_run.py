@@ -1,5 +1,6 @@
 """The harness's entry: it refuses to report without a chip, and
 ``BENCHMARK.json`` names only what the harness can find by name."""
+import inspect
 import json
 import os
 import re
@@ -35,7 +36,8 @@ def test_no_chip_no_result(bench):
 
 def test_every_name_is_found(bench):
     """Each cell's configuration file, mix, limits and model module, and
-    each metric's reader, exist under the name the harness looks for."""
+    each metric's reader, exist under the name the harness looks for;
+    each model module provides the family contract (``run.py``)."""
     configs = {c["name"]: c for c in bench["configs"]}
     for w in bench["workloads"]:
         c = configs[w["config"]]
@@ -47,9 +49,25 @@ def test_every_name_is_found(bench):
                      f"limits/{w['name']}.json",
                      f"models/{cfg['model']}.py"):
             assert os.path.isfile(os.path.join(HERE, path)), path
+        _check_family(os.path.join(HERE, "models", f"{cfg['model']}.py"))
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert os.path.isfile(os.path.join(HERE, "metrics",
                                            f"{m['name']}.py")), m["name"]
+
+
+def _check_family(path):
+    sys.path.insert(0, HERE)
+    import run as R
+    fam = R._load(path, "bench_family_" + os.path.basename(path)[:-3])
+    for name in ("program_config", "program_weights", "Reference", "work"):
+        assert callable(getattr(fam, name, None)), (path, name)
+    ref = inspect.signature(fam.Reference)
+    # (cfg, seed, device, max_len, control=False)
+    assert len(ref.parameters) >= 5 and "control" in ref.parameters, \
+        (path, ref)
+    counters = getattr(fam, "COUNTERS", ())
+    assert isinstance(counters, tuple) and all(
+        isinstance(k, str) for k in counters), (path, counters)
 
 
 def test_names_and_units(bench):

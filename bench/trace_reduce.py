@@ -48,13 +48,6 @@ class TraceSummary:
         hits = [v for k, v in self.programs.items() if rx.search(k)]
         return sum(s for s, _ in hits), sum(n for _, n in hits)
 
-    def op_seconds(self, pattern: str) -> Tuple[float, int]:
-        """Self seconds and executions of the operation kinds that match
-        ``pattern`` (a regular expression)."""
-        rx = re.compile(pattern)
-        hits = [v for k, v in self.ops.items() if rx.search(k)]
-        return sum(s for s, _ in hits), sum(n for _, n in hits)
-
 
 def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     out: List[List[float]] = []
